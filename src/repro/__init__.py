@@ -5,18 +5,13 @@ Space on FPGAs for Large-Scale Hardware Acceleration Infrastructure"
 (Arthanto, Ojika, Kim — CS.DC 2022).  See DESIGN.md / EXPERIMENTS.md.
 """
 
-from repro import compat as _compat
-
-_compat.install()
-
 __all__ = ["dist"]
 __version__ = "1.1.0"
 
 
 def __getattr__(name):
     # Lazy re-export: `repro.dist` pulls in the full model/optim stack, which
-    # lightweight consumers (e.g. the analytic netmodel) shouldn't pay for —
-    # only the compat shims must run at package import.
+    # lightweight consumers (e.g. the analytic netmodel) shouldn't pay for.
     if name == "dist":
         import importlib
 

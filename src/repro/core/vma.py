@@ -1,4 +1,4 @@
-"""Varying-manual-axes helpers for shard_map code (JAX >= 0.7 vma tracking).
+"""Varying-manual-axes helpers for shard_map code (vma tracking).
 
 Inside ``shard_map``, constants are *unvarying* over the mesh axes while
 anything derived from permuted/indexed data is *varying*.  ``lax.scan`` /
@@ -13,15 +13,9 @@ from __future__ import annotations
 import jax
 from jax import lax
 
-# Pre-vma jax (< 0.7) has no varying-axes tracking inside shard_map: every
-# value is implicitly varying and the promotion is a no-op.
-_HAS_VMA = hasattr(jax, "typeof") and hasattr(lax, "pcast")
-
 
 def vary(x, axis: str | tuple[str, ...]):
     """Promote ``x`` to varying over ``axis`` (no-op if already varying)."""
-    if not _HAS_VMA:
-        return x
     axes = (axis,) if isinstance(axis, str) else tuple(axis)
     vma = getattr(jax.typeof(x), "vma", frozenset())
     missing = tuple(a for a in axes if a not in vma)

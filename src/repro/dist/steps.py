@@ -30,8 +30,8 @@ Responsibilities:
     gradient hop has its own PGAS conduit in ``dist/grad_sync.py``
     (operating on per-pod gradients, pod-sharded layout); wiring it
     *inside* this GSPMD step would require partial-manual shard_map over
-    ``pod``, which the pinned jax's partitioner rejects — see DESIGN §6
-    and the ROADMAP open item.
+    ``pod``, which the SPMD partitioner rejected — see DESIGN §6 and the
+    ROADMAP open item.
 
 See ``docs/api.md`` for the public surface and ``docs/transports.md`` for
 the op × transport support matrix these policies select from.
@@ -357,6 +357,41 @@ def _art_runner(cfg: ModelConfig, mesh,
 
 
 # ---------------------------------------------------------------------------
+# Pallas attention runner (the kernel inside a shard_map)
+# ---------------------------------------------------------------------------
+
+
+def _attention_runner(cfg: ModelConfig, mesh) -> Optional[Callable]:
+    """Flash-attention runner for meshes of more than one device.
+
+    GSPMD cannot partition a Mosaic kernel, so the Pallas call runs inside
+    a ``shard_map`` whose specs shard batch over the data axes and heads
+    over ``model`` wherever they divide (both q and kv heads must, so each
+    shard keeps whole GQA groups); axes that divide nothing replicate.
+    Returns None on one device, or when attention does not resolve to the
+    kernel — the model then calls it (or the jnp path) directly.
+    """
+    if mesh.size == 1 or L.resolve_attn_impl(cfg) != "pallas":
+        return None
+    from repro.kernels.flash_attention import flash_attention
+
+    dp = dp_axes(mesh)
+    tp = "model" if "model" in mesh.axis_names else None
+
+    def runner(q, k, v, **kw):
+        heads = fit_axis(mesh, tp, q.shape[1])
+        if fit_axis(mesh, tp, k.shape[1]) is None:
+            heads = None
+        spec = P(fit_axis(mesh, dp, q.shape[0]), heads, None, None)
+        return jax.shard_map(
+            functools.partial(flash_attention, **kw), mesh=mesh,
+            in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False)(q, k, v)
+
+    return runner
+
+
+# ---------------------------------------------------------------------------
 # expert-parallel MoE runner (conduit all_to_all dispatch)
 # ---------------------------------------------------------------------------
 
@@ -407,7 +442,10 @@ def build_init(cfg: ModelConfig, mesh, scfg: StepConfig):
 
 def build_train_step(cfg: ModelConfig, mesh, scfg: StepConfig,
                      bshape) -> StepBundle:
-    """``fn(params, opt, batch, step) -> (params, opt, metrics)``."""
+    """``fn(params, opt, batch, step) -> (params, opt, metrics)``.
+
+    ``params`` and ``opt`` are **donated**: their buffers are reused for
+    the outputs, so a caller must not read them after the call."""
     params_shape, opt_shape = _state_shapes(cfg, scfg)
     pspecs = param_pspecs(cfg, mesh, params_shape)
     ospecs = opt_pspecs(cfg, mesh, opt_shape, pspecs)
@@ -417,11 +455,12 @@ def build_train_step(cfg: ModelConfig, mesh, scfg: StepConfig,
     policy = scfg.resolved_transport()
     runner = _art_runner(cfg, mesh, policy)
     moe_runner = _moe_runner(cfg, mesh, policy)
+    attn_runner = _attention_runner(cfg, mesh)
     n_micro = max(int(scfg.microbatches), 1)
 
     def loss_fn(params, microbatch):
         with activation_sharding(constrain, tp_block=runner,
-                                 moe_ffn=moe_runner):
+                                 moe_ffn=moe_runner, attention=attn_runner):
             return chunked_ce_loss(
                 cfg, params, microbatch, seq_chunk=scfg.seq_chunk,
                 z_loss=scfg.z_loss, moe_aux_weight=scfg.moe_aux_weight)
@@ -489,8 +528,10 @@ def build_train_step(cfg: ModelConfig, mesh, scfg: StepConfig,
     osh = to_shardings(mesh, ospecs)
     bsh = to_shardings(mesh, bspecs)
     scalar = _scalar_sharding(mesh)
+    # params and optimizer state are donated: the step replaces both, and
+    # every caller rebinds them to its outputs
     fn = jax.jit(step_fn, in_shardings=(psh, osh, bsh, scalar),
-                 out_shardings=(psh, osh, scalar))
+                 out_shardings=(psh, osh, scalar), donate_argnums=(0, 1))
     return StepBundle(
         fn=fn,
         in_specs=(pspecs, ospecs, bspecs, P()),
@@ -525,6 +566,7 @@ def build_prefill_step(cfg: ModelConfig, mesh, scfg: StepConfig,
     params_shape, _ = _state_shapes(cfg, scfg)
     pspecs = param_pspecs(cfg, mesh, params_shape)
     constrain = _constraint_fn(cfg, mesh, scfg)
+    attn_runner = _attention_runner(cfg, mesh)
     dp = dp_axes(mesh)
     n_chunks = int(chunks or 1)
     cap = cache_len or seq_len
@@ -548,14 +590,14 @@ def build_prefill_step(cfg: ModelConfig, mesh, scfg: StepConfig,
             return run(params, tokens)
 
         def fwd(params, tokens):
-            with activation_sharding(constrain):
+            with activation_sharding(constrain, attention=attn_runner):
                 return run(params, tokens)
     else:
         def raw(params, tokens, fe):
             return run(params, tokens, fe)
 
         def fwd(params, tokens, fe):
-            with activation_sharding(constrain):
+            with activation_sharding(constrain, attention=attn_runner):
                 return run(params, tokens, fe)
 
     cache_shape, logits_shape = jax.eval_shape(raw, params_shape, *arg_shapes)

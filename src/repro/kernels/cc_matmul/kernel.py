@@ -47,9 +47,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _compiler_params(collective_id: int):
-    """Cross-version compiler params (renamed TPUCompilerParams → ...)."""
-    cls = getattr(pltpu, "TPUCompilerParams", None) or pltpu.CompilerParams
-    return cls(has_side_effects=True, collective_id=collective_id)
+    return pltpu.CompilerParams(has_side_effects=True,
+                                collective_id=collective_id)
 
 
 # ---------------------------------------------------------------------------
@@ -123,6 +122,13 @@ def consume_matmul_acc(scratch: jnp.ndarray, x: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
+def _peer(axis: str, rank):
+    """Remote-DMA address of ``rank`` on ring ``axis``: mesh coordinates,
+    with every other mesh axis held at this device's own index."""
+    return dict(device_id={axis: rank},
+                device_id_type=pltpu.DeviceIdType.MESH)
+
+
 def _neighbor_barrier(axis: str, n: int):
     """Rendezvous with both ring neighbors before touching their VMEM —
     the standard guard against a fast rank DMA-ing into a peer whose
@@ -130,14 +136,24 @@ def _neighbor_barrier(axis: str, n: int):
     my = lax.axis_index(axis)
     barrier = pltpu.get_barrier_semaphore()
     for nb in (1, n - 1):
-        pltpu.semaphore_signal(
-            barrier, inc=1, device_id=((my + nb) % n,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL)
+        pltpu.semaphore_signal(barrier, inc=1, **_peer(axis, (my + nb) % n))
     pltpu.semaphore_wait(barrier, 2)
 
 
+def _slot_free(ready_sem, axis: str, n: int, direction: int):
+    """Tell the upstream rank (the one that DMAs into this rank) that the
+    slot it writes next is no longer read here.  Paired one-for-one with
+    a ``semaphore_wait(ready_sem, 1)`` before each send into a slot the
+    downstream rank has used, so a fast rank never overwrites a block its
+    neighbor is still multiplying or forwarding."""
+    my = lax.axis_index(axis)
+    pltpu.semaphore_signal(ready_sem, inc=1,
+                           **_peer(axis, (my - direction) % n))
+
+
 def _ag_ring_kernel(x_ref, w_ref, o_ref, comm_ref, local_sem, send_sem,
-                    recv_sem, *, axis: str, n: int, direction: int):
+                    recv_sem, ready_sem, *, axis: str, n: int,
+                    direction: int):
     my = lax.axis_index(axis)
     b = x_ref.shape[0]
     _neighbor_barrier(axis, n)
@@ -153,11 +169,13 @@ def _ag_ring_kernel(x_ref, w_ref, o_ref, comm_ref, local_sem, send_sem,
             src_ref=comm_ref.at[hop % 2],
             dst_ref=comm_ref.at[(hop + 1) % 2],
             send_sem=send_sem, recv_sem=recv_sem,
-            device_id=((my + direction) % n,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL)
+            **_peer(axis, (my + direction) % n))
 
     for hop in range(n):
         if hop + 1 < n:
+            if hop >= 1:
+                # the downstream slot held its hop-(hop-1) block
+                pltpu.semaphore_wait(ready_sem, 1)
             rdma(hop).start()               # hop k+1's chunk in flight ...
         src = (my - direction * hop) % n
         o_ref[pl.ds(src * b, b), :] = jnp.dot(
@@ -165,6 +183,8 @@ def _ag_ring_kernel(x_ref, w_ref, o_ref, comm_ref, local_sem, send_sem,
             preferred_element_type=jnp.float32)  # ... while hop k multiplies
         if hop + 1 < n:
             rdma(hop).wait()                # fence both slots before reuse
+            if hop + 2 < n:
+                _slot_free(ready_sem, axis, n, direction)
 
 
 def ag_matmul_ring_tpu(x: jnp.ndarray, w: jnp.ndarray, *, axis: str,
@@ -184,13 +204,15 @@ def ag_matmul_ring_tpu(x: jnp.ndarray, w: jnp.ndarray, *, axis: str,
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
+            pltpu.SemaphoreType.REGULAR,
         ],
         compiler_params=_compiler_params(collective_id),
     )(x, w)
 
 
 def _rs_ring_kernel(x_ref, w_ref, o_ref, comm_ref, send_sem, recv_sem,
-                    *, axis: str, n: int, direction: int, b_loc: int):
+                    ready_sem, *, axis: str, n: int, direction: int,
+                    b_loc: int):
     my = lax.axis_index(axis)
 
     def partial_block(hop):
@@ -208,14 +230,18 @@ def _rs_ring_kernel(x_ref, w_ref, o_ref, comm_ref, send_sem, recv_sem,
             src_ref=comm_ref.at[(hop - 1) % 2],
             dst_ref=comm_ref.at[hop % 2],
             send_sem=send_sem, recv_sem=recv_sem,
-            device_id=((my + direction) % n,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL)
+            **_peer(axis, (my + direction) % n))
 
     for hop in range(1, n):
+        if hop >= 2:
+            # the downstream slot still held its hop-(hop-1) send source
+            pltpu.semaphore_wait(ready_sem, 1)
         rdma(hop).start()                   # accumulator rides the ring ...
         part = partial_block(hop)           # ... under the local partial
         rdma(hop).wait()
         comm_ref[hop % 2] = comm_ref[hop % 2] + part
+        if hop + 1 < n:
+            _slot_free(ready_sem, axis, n, direction)
 
     o_ref[...] = comm_ref[(n - 1) % 2]
 
@@ -234,6 +260,7 @@ def rs_matmul_ring_tpu(x: jnp.ndarray, w: jnp.ndarray, *, axis: str,
             pltpu.VMEM((2, b_loc, w.shape[1]), jnp.float32),
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
+            pltpu.SemaphoreType.REGULAR,
         ],
         compiler_params=_compiler_params(collective_id),
     )(x, w)

@@ -1,4 +1,5 @@
-"""jit'd wrapper for flash attention: padding + CPU interpret fallback."""
+"""jit'd wrapper for flash attention: padding, CPU interpret fallback, and
+the gradient rule that lets the Pallas forward serve training too."""
 
 from __future__ import annotations
 
@@ -16,6 +17,40 @@ def _pad_seq(x: jnp.ndarray, mult: int) -> jnp.ndarray:
     if pad == 0:
         return x
     return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, window, scale, block_q, block_kv, interpret):
+    sq = q.shape[2]
+    qp, kp, vp = _pad_seq(q, block_q), _pad_seq(k, block_kv), _pad_seq(v, block_kv)
+    out = flash_attention_pallas(
+        qp, kp, vp,
+        causal=causal, window=window, scale=scale,
+        block_q=block_q, block_kv=block_kv, interpret=interpret,
+    )
+    return out[:, :, :sq, :]
+
+
+def _flash_fwd(q, k, v, causal, window, scale, block_q, block_kv, interpret):
+    out = _flash(q, k, v, causal, window, scale, block_q, block_kv, interpret)
+    return out, (q, k, v)
+
+
+def _flash_bwd(causal, window, scale, block_q, block_kv, interpret, res, g):
+    # The backward is the VJP of the blockwise jnp attention on the same
+    # inputs: the same function (online softmax, same masks), recomputed
+    # in f32 and differentiated by JAX.
+    from repro.models.layers import blockwise_attention
+
+    q, k, v = res
+    _, vjp = jax.vjp(
+        functools.partial(blockwise_attention, causal=causal, window=window,
+                          scale=scale),
+        q, k, v)
+    return vjp(g)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 @functools.partial(
@@ -41,6 +76,9 @@ def flash_attention(
     invisible to real queries, and padded query rows are cropped.
     For non-causal use, padded kv would attend — so we require causal or
     explicit full blocks there (asserted).
+
+    Differentiable: the gradient is that of
+    :func:`repro.models.layers.blockwise_attention` on the same inputs.
     """
     if interpret is None:
         interpret = should_interpret()
@@ -49,10 +87,5 @@ def flash_attention(
         assert sq % block_q == 0 and skv % block_kv == 0, (
             "non-causal attention requires block-aligned sequence lengths "
             f"(got {sq=}, {skv=})")
-    qp, kp, vp = _pad_seq(q, block_q), _pad_seq(k, block_kv), _pad_seq(v, block_kv)
-    out = flash_attention_pallas(
-        qp, kp, vp,
-        causal=causal, window=window, scale=scale,
-        block_q=block_q, block_kv=block_kv, interpret=interpret,
-    )
-    return out[:, :, :sq, :]
+    return _flash(q, k, v, causal, window, scale, block_q, block_kv,
+                  interpret)

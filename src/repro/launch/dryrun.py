@@ -1,10 +1,12 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
-The two lines above MUST stay the first statements — jax locks the device
-count on first init, and the production meshes need 512 host devices.
+The three lines above MUST stay the first statements — jax locks the
+platform and device count on first init, and the production meshes need
+512 virtual host devices (on a machine with an accelerator too).
 
 Per cell:
     with mesh:

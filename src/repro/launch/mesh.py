@@ -26,16 +26,20 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(data: int = 2, model: int = 2, expert: int = 1):
-    """Small CPU mesh for tests/examples (requires the host-device flag).
+    """Small mesh over the local devices: CPU host devices for tests and
+    examples (requires the host-device flag), or the chips of one host.
 
     ``expert`` > 1 appends an ``expert`` axis (EP dispatch —
     ``models/moe_ep.py``); dense archs treat it as one more data axis.
     """
     n = data * model * expert
     avail = len(jax.devices())
-    assert avail >= n, (
-        f"need {n} devices, have {avail}; set "
-        f"XLA_FLAGS=--xla_force_host_platform_device_count={n}")
+    if avail < n:
+        hint = ("; set XLA_FLAGS=--xla_force_host_platform_device_count="
+                f"{n}" if jax.default_backend() == "cpu" else
+                f"; this {jax.default_backend()} host has {avail} — pass "
+                f"smaller --data-axis/--model-axis")
+        raise ValueError(f"need {n} devices, have {avail}{hint}")
     auto = jax.sharding.AxisType.Auto
     if expert > 1:
         return jax.make_mesh((data, model, expert),

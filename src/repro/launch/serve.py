@@ -1,12 +1,14 @@
 """Serving launcher: ``python -m repro.launch.serve --arch smollm-360m``.
 
-Continuous batching with chunked streamed prefill over a CPU mesh with
-reduced configs; the production path is identical modulo mesh + config
-size (dry-run covers the full-scale lowering).  ``--prefill-chunk 0``
-falls back to bulk per-slot admission (the head-of-line-blocking
-baseline the chunked scheduler exists to kill); ``--expert-axis`` +
-``--moe-transport`` route MoE decode through the expert-parallel conduit
-dispatch (``docs/serving.md``).
+Continuous batching with chunked streamed prefill.  By default it serves
+the reduced config over a CPU host mesh; ``--full`` serves the published
+widths (on one TPU chip: ``--full --data-axis 1 --model-axis 1``).
+``main(argv)`` runs in-process and returns the :class:`Server`, so a
+caller that holds the chip drives it without a child process.
+``--prefill-chunk 0`` falls back to bulk per-slot admission (the
+head-of-line-blocking baseline the chunked scheduler exists to kill);
+``--expert-axis`` + ``--moe-transport`` route MoE decode through the
+expert-parallel conduit dispatch (``docs/serving.md``).
 """
 
 from __future__ import annotations
@@ -17,13 +19,15 @@ import os
 import numpy as np
 
 
-def main():
+def main(argv=None):
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--arch", default="smollm-360m")
     p.add_argument("--requests", type=int, default=16)
     p.add_argument("--prompt-len", type=int, default=16)
     p.add_argument("--max-new", type=int, default=24)
     p.add_argument("--max-batch", type=int, default=4)
+    p.add_argument("--max-seq", type=int, default=256,
+                   help="per-slot cache extent (prompt + new tokens)")
     p.add_argument("--data-axis", type=int, default=2)
     p.add_argument("--model-axis", type=int, default=2)
     p.add_argument("--expert-axis", type=int, default=1,
@@ -63,7 +67,10 @@ def main():
                         "detector — NOT scripted raises (requires "
                         "--paged; tokens stay identical to an unfailed "
                         "run)")
-    args = p.parse_args()
+    p.add_argument("--full", action="store_true",
+                   help="serve the published widths instead of the "
+                        "reduced config")
+    args = p.parse_args(argv)
 
     n_dev = args.data_axis * args.model_axis * args.expert_axis
     os.environ.setdefault(
@@ -73,11 +80,15 @@ def main():
     from repro.configs import get_config
     from repro.dist.sharding import param_pspecs, to_shardings
     from repro.dist.steps import StepConfig, TransportPolicy
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.models.model import init_params
     from repro.runtime.server import Server, ServerConfig, drive_arrivals
 
-    cfg = get_config(args.arch).reduced()
+    enable_compile_cache()
+    cfg = get_config(args.arch)
+    if not args.full:
+        cfg = cfg.reduced()
     mesh = make_host_mesh(args.data_axis, args.model_axis, args.expert_axis)
     params_shape = jax.eval_shape(
         lambda k: init_params(cfg, k), jax.random.PRNGKey(0))
@@ -115,7 +126,8 @@ def main():
         membership = MembershipService(n_pool, lease, fault_plan=plan)
         membership.schedule_join(victims[0], at_step=kill_at + 10)
     srv = Server(cfg, params, mesh, scfg=scfg, srv=ServerConfig(
-        max_batch=args.max_batch, max_seq=256, max_new_tokens=args.max_new,
+        max_batch=args.max_batch, max_seq=args.max_seq,
+        max_new_tokens=args.max_new,
         prefill_chunk=args.prefill_chunk or None,
         paged=args.paged, block_size=args.block_size), fault_plan=plan,
         membership=membership)
@@ -192,6 +204,7 @@ def main():
         with open(args.dump_tokens, "w") as f:
             json.dump({str(r.rid): r.out_tokens for r in srv.done}, f,
                       sort_keys=True)
+    return srv
 
 
 if __name__ == "__main__":

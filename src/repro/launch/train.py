@@ -1,9 +1,11 @@
 """Training launcher: ``python -m repro.launch.train --arch smollm-360m``.
 
-On this CPU container it drives *reduced* configs end-to-end (the full
-configs are exercised by the dry-run); on a real pod the same launcher
-binds the production mesh and full config.  All fault-tolerance features
-(checkpoint/restart, preemption, straggler watchdog) are live either way.
+By default it trains the *reduced* config over a CPU host mesh; ``--full``
+trains the published widths (on one TPU chip: ``--full --data-axis 1
+--model-axis 1``).  ``main(argv)`` runs in-process and returns the
+:class:`Trainer` with the final ``(params, opt, step)``.  All
+fault-tolerance features (checkpoint/restart, preemption, straggler
+watchdog) are live either way.
 """
 
 from __future__ import annotations
@@ -12,7 +14,7 @@ import argparse
 import os
 
 
-def main():
+def main(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="smollm-360m")
     p.add_argument("--steps", type=int, default=200)
@@ -39,7 +41,7 @@ def main():
     p.add_argument("--ckpt-interval", type=int, default=50)
     p.add_argument("--reduced", action="store_true", default=True)
     p.add_argument("--full", dest="reduced", action="store_false")
-    args = p.parse_args()
+    args = p.parse_args(argv)
 
     n_dev = args.data_axis * args.model_axis * args.expert_axis
     os.environ.setdefault(
@@ -48,9 +50,11 @@ def main():
     from repro.configs import get_config
     from repro.data import DataConfig, SyntheticLM
     from repro.dist.steps import StepConfig, TransportPolicy
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.runtime.trainer import Trainer, TrainerConfig
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -75,6 +79,7 @@ def main():
     params, opt, step = trainer.train()
     print(f"[train] finished at step {step}; "
           f"final loss {trainer.history[-1]['loss']:.4f}")
+    return trainer, (params, opt, step)
 
 
 if __name__ == "__main__":

@@ -263,8 +263,10 @@ def attention_core(
     aligned = q_offset is None or q_offset == k.shape[2] - q.shape[2]
     if impl == "pallas" and q.shape[-1] == v.shape[-1] and aligned:
         from repro.kernels.flash_attention import flash_attention
+        from repro.models.shardctx import attention_runner
 
-        return flash_attention(q, k, v, causal=causal, window=window, scale=scale)
+        run = attention_runner() or flash_attention
+        return run(q, k, v, causal=causal, window=window, scale=scale)
     if impl == "ref" and aligned:
         from repro.kernels.flash_attention.ref import attention_ref
 

@@ -21,11 +21,13 @@ from typing import Callable, Optional
 _ACTIVE: Optional[Callable] = None
 _TP_BLOCK: Optional[Callable] = None
 _MOE_FFN: Optional[Callable] = None
+_ATTN: Optional[Callable] = None
 
 
 @contextlib.contextmanager
 def activation_sharding(fn: Callable, tp_block: Optional[Callable] = None,
-                        moe_ffn: Optional[Callable] = None):
+                        moe_ffn: Optional[Callable] = None,
+                        attention: Optional[Callable] = None):
     """``fn(x, tag)`` applies sharding constraints.
 
     ``tp_block`` (optional) is the ART-TP dense-block runner installed by
@@ -38,14 +40,20 @@ def activation_sharding(fn: Callable, tp_block: Optional[Callable] = None,
     ``TransportPolicy.moe`` names a conduit transport and the mesh has an
     ``expert`` axis: ``moe_ffn(cfg, moe_params, x) -> y`` replaces
     ``layers.moe`` with the bucketed all_to_all dispatch of
-    ``models/moe_ep.py``."""
-    global _ACTIVE, _TP_BLOCK, _MOE_FFN
-    old, old_tp, old_moe = _ACTIVE, _TP_BLOCK, _MOE_FFN
-    _ACTIVE, _TP_BLOCK, _MOE_FFN = fn, tp_block, moe_ffn
+    ``models/moe_ep.py``.
+
+    ``attention`` (optional) is the Pallas attention runner installed when
+    the mesh has more than one device: ``attention(q, k, v, causal=,
+    window=, scale=) -> out`` runs the flash kernel inside a ``shard_map``
+    over the axes that shard batch and heads (GSPMD cannot partition a
+    Mosaic kernel)."""
+    global _ACTIVE, _TP_BLOCK, _MOE_FFN, _ATTN
+    old = _ACTIVE, _TP_BLOCK, _MOE_FFN, _ATTN
+    _ACTIVE, _TP_BLOCK, _MOE_FFN, _ATTN = fn, tp_block, moe_ffn, attention
     try:
         yield
     finally:
-        _ACTIVE, _TP_BLOCK, _MOE_FFN = old, old_tp, old_moe
+        _ACTIVE, _TP_BLOCK, _MOE_FFN, _ATTN = old
 
 
 def constrain(x, tag: str):
@@ -61,3 +69,9 @@ def tp_block_runner() -> Optional[Callable]:
 def moe_ffn_runner() -> Optional[Callable]:
     """The installed expert-parallel MoE runner, or None (dense GSPMD)."""
     return _MOE_FFN
+
+
+def attention_runner() -> Optional[Callable]:
+    """The installed sharded Pallas attention runner, or None (call the
+    kernel directly: one device, or outside a step builder)."""
+    return _ATTN

@@ -2,10 +2,14 @@
 
 Failure model and responses (DESIGN §6):
 
-  device/host loss     -> catch, ``elastic.remesh`` excluding dead devices,
-                          rebuild the step on the new mesh, restore the last
-                          checkpoint resharded, resume (data pipeline is
-                          stateless — nothing else to recover)
+  device/host loss     -> catch the typed ``RankFailure`` (or take the
+                          membership detector's declaration), ``elastic``
+                          re-mesh excluding the dead member, rebuild the
+                          step on the new mesh, restore the last checkpoint
+                          resharded, resume (data pipeline is stateless —
+                          nothing else to recover).  Any other exception
+                          (a compile error, an OOM) propagates: retrying
+                          it would loop
   straggler            -> per-step wall-clock watchdog; a step slower than
                           ``straggler_factor ×`` the trailing median is
                           flagged; after ``straggler_patience`` consecutive
@@ -168,7 +172,7 @@ class Trainer:
                 params, opt, metrics = self.bundle.fn(
                     params, opt, batch, jnp.int32(step))
                 jax.block_until_ready(metrics["loss"])
-            except Exception as e:      # device loss / comm failure
+            except RankFailure as e:    # a named member died mid-step
                 n_failures += 1
                 self.log(f"[trainer] step {step} failed ({type(e).__name__}: "
                          f"{e}); elastic recovery #{n_failures}")
@@ -208,16 +212,13 @@ class Trainer:
                               if self.history else None})
         return params, opt, step
 
-    def _recover_mesh(self, mesh, failure: Optional[Exception] = None):
-        """Rebuild the mesh from the devices that still respond.
+    def _recover_mesh(self, mesh, failure: RankFailure):
+        """Rebuild the mesh without the member ``failure`` names.
 
-        A typed :class:`~repro.core.conduit.RankFailure` names the dead
-        member; the :class:`~repro.runtime.elastic.ElasticRuntime` then
-        excludes it, re-forms the conduits, and scales grad accumulation
+        The :class:`~repro.runtime.elastic.ElasticRuntime` excludes the
+        dead member, re-forms the conduits, and scales grad accumulation
         so the global batch survives the data-axis shrink (the rebuilt
         step bundle picks the new ``microbatches`` up from ``self.scfg``).
-        Untyped failures keep the legacy behavior: rebuild over whatever
-        ``jax.devices()`` still answers.
         """
         model = mesh.shape.get("model", 1)
         if self.elastic is None:
@@ -225,19 +226,17 @@ class Trainer:
                 model=model, axis_names=tuple(mesh.axis_names),
                 devices=list(mesh.devices.flat),
                 fault_plan=self.fault_plan)
-        if isinstance(failure, RankFailure):
-            report = self.elastic.on_failure(
-                failure, microbatches=self.scfg.microbatches,
-                grad_bucket_bytes=self.scfg.grad_bucket_bytes
-                or DEFAULT_BUCKET_BYTES)
-            old_data = dict(report.old_shape).get("data", 1)
-            new_data = dict(report.new_shape).get("data", 1)
-            if new_data != old_data:
-                self.log(f"[trainer] data axis {old_data} -> {new_data}: "
-                         f"grad accumulation x{old_data // new_data} "
-                         f"to hold the global batch")
-                self.scfg = refit_step_config(self.scfg, old_data, new_data)
-            return self.elastic.mesh()
+        report = self.elastic.on_failure(
+            failure, microbatches=self.scfg.microbatches,
+            grad_bucket_bytes=self.scfg.grad_bucket_bytes
+            or DEFAULT_BUCKET_BYTES)
+        old_data = dict(report.old_shape).get("data", 1)
+        new_data = dict(report.new_shape).get("data", 1)
+        if new_data != old_data:
+            self.log(f"[trainer] data axis {old_data} -> {new_data}: "
+                     f"grad accumulation x{old_data // new_data} "
+                     f"to hold the global batch")
+            self.scfg = refit_step_config(self.scfg, old_data, new_data)
         return self.elastic.mesh()
 
     def _scale_out(self, mesh, device=None):

@@ -11,117 +11,10 @@ import sys
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 
-
-def _install_hypothesis_fallback():
-    """Register a minimal deterministic `hypothesis` stand-in when the real
-    library is absent (the pinned container has no network; CI installs the
-    real one via `pip install -e .[test]`).  Supports exactly the subset the
-    suite uses: @given(**kwargs) + @settings(max_examples, deadline) with
-    st.integers / st.sampled_from / st.tuples / st.lists.  Draws are
-    deterministic: the bounds first, then seeded pseudo-random interior
-    points (lists draw the empty boundary first, then seeded contents).
-    """
-    try:
-        import hypothesis  # noqa: F401
-        return
-    except ImportError:
-        pass
-
-    import functools
-    import random
-    import types
-
-    class _Integers:
-        def __init__(self, lo, hi):
-            self.lo, self.hi = lo, hi
-
-        def draw(self, i, rng):
-            if i == 0:
-                return self.lo
-            if i == 1:
-                return self.hi
-            return rng.randint(self.lo, self.hi)
-
-    class _SampledFrom:
-        def __init__(self, elems):
-            self.elems = list(elems)
-
-        def draw(self, i, rng):
-            if i < len(self.elems):
-                return self.elems[i]
-            return rng.choice(self.elems)
-
-    class _Tuples:
-        def __init__(self, *elems):
-            self.elems = elems
-
-        def draw(self, i, rng):
-            return tuple(s.draw(i, rng) for s in self.elems)
-
-    class _Lists:
-        def __init__(self, elements, min_size=0, max_size=10):
-            self.elements = elements
-            self.min_size, self.max_size = min_size, max_size
-
-        def draw(self, i, rng):
-            if i == 0:
-                n = self.min_size
-            else:
-                n = rng.randint(self.min_size, self.max_size)
-            # force every element onto the seeded-random interior path
-            # (a boundary index would repeat one element n times)
-            return [self.elements.draw(1 << 20, rng) for _ in range(n)]
-
-    def settings(max_examples=None, deadline=None, **_kw):
-        def deco(fn):
-            if max_examples is not None:
-                fn._stub_max_examples = max_examples
-            return fn
-        return deco
-
-    def given(**strategies):
-        def deco(fn):
-            n = getattr(fn, "_stub_max_examples", 10)
-
-            @functools.wraps(fn)
-            def wrapper(*args):
-                rng = random.Random(fn.__qualname__)
-                for i in range(n):
-                    kwargs = {k: s.draw(i, rng)
-                              for k, s in strategies.items()}
-                    fn(*args, **kwargs)
-
-            # hide the strategy kwargs from pytest's fixture resolution
-            import inspect
-            params = [p for name, p in
-                      inspect.signature(fn).parameters.items()
-                      if name not in strategies]
-            wrapper.__signature__ = inspect.Signature(params)
-            del wrapper.__wrapped__
-            return wrapper
-        return deco
-
-    st_mod = types.ModuleType("hypothesis.strategies")
-    st_mod.integers = _Integers
-    st_mod.sampled_from = _SampledFrom
-    st_mod.tuples = _Tuples
-    st_mod.lists = _Lists
-    hyp = types.ModuleType("hypothesis")
-    hyp.given = given
-    hyp.settings = settings
-    hyp.strategies = st_mod
-    hyp.__stub__ = True
-    sys.modules["hypothesis"] = hyp
-    sys.modules["hypothesis.strategies"] = st_mod
-
-
-_install_hypothesis_fallback()
-
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir, "src"))
-import repro  # noqa: E402,F401  (installs jax compat shims for fixtures)
 
 
 @pytest.fixture(scope="session")

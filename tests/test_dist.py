@@ -203,6 +203,55 @@ class TestServePrefill:
                                    rtol=2e-4, atol=2e-4)
 
 
+class TestShardedPallasAttention:
+    """The flash kernel inside a shard_map (GSPMD cannot partition a
+    Mosaic kernel): the sharded call equals the unsharded one."""
+
+    def _cfg(self):
+        cfg = get_config("smollm-360m").reduced()
+        return dataclasses.replace(cfg, attn_impl="pallas")
+
+    @pytest.mark.parametrize("shape", [(2, 2), (1, 4), (4, 1)])
+    def test_runner_equals_unsharded(self, shape):
+        from repro.dist.steps import _attention_runner
+        from repro.kernels.flash_attention import flash_attention
+        from repro.launch.mesh import make_host_mesh
+
+        mesh = make_host_mesh(*shape)
+        runner = _attention_runner(self._cfg(), mesh)
+        q = jax.random.normal(jax.random.PRNGKey(0), (4, 4, 40, 16))
+        k = jax.random.normal(jax.random.PRNGKey(1), (4, 4 // 2, 40, 16))
+        v = jax.random.normal(jax.random.PRNGKey(2), (4, 4 // 2, 40, 16))
+        got = jax.jit(functools.partial(runner, causal=True, window=None,
+                                        scale=None))(q, k, v)
+        want = flash_attention(q, k, v, causal=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def test_one_device_calls_kernel_directly(self):
+        from repro.dist.steps import _attention_runner
+        from repro.launch.mesh import make_host_mesh
+
+        assert _attention_runner(self._cfg(), make_host_mesh(1, 1)) is None
+        jnp_cfg = get_config("smollm-360m").reduced()
+        assert _attention_runner(jnp_cfg, make_host_mesh(2, 2)) is None
+
+    def test_prefill_step_matches_unsharded(self, mesh22):
+        cfg = self._cfg()
+        bundle = build_prefill_step(cfg, mesh22, StepConfig(), batch=4,
+                                    seq_len=16)
+        init_fn, _ = build_init(cfg, mesh22, StepConfig())
+        params, _ = init_fn(jax.random.PRNGKey(0))
+        toks = jax.random.randint(jax.random.PRNGKey(2), (4, 16), 0,
+                                  cfg.vocab_size)
+        _, logits = bundle.fn(params, toks)
+        from repro.models.prefill import prefill
+        _, logits_ref = prefill(cfg, jax.device_get(params), toks,
+                                cache_len=16)
+        np.testing.assert_allclose(np.asarray(logits),
+                                   np.asarray(logits_ref),
+                                   rtol=2e-4, atol=2e-4)
+
+
 class TestArtTP:
     """The paper's technique as a training feature: ART ring schedules for
     TP collectives must be numerically identical to the GSPMD baseline and

@@ -131,6 +131,30 @@ class TestFlashAttention:
             np.asarray(got, np.float32), np.asarray(want, np.float32),
             rtol=3e-2, atol=3e-2)
 
+    @pytest.mark.parametrize("causal,window,sq", [(True, None, 100),
+                                                   (True, 24, 72),
+                                                   (False, None, 128)])
+    def test_grads_match_blockwise(self, causal, window, sq):
+        """The custom VJP: grads of the Pallas forward are those of
+        layers.blockwise_attention on the same inputs."""
+        from repro.models.layers import blockwise_attention
+
+        q = jax.random.normal(jax.random.PRNGKey(0), (2, 4, sq, 16))
+        k = jax.random.normal(jax.random.PRNGKey(1), (2, 2, sq, 16))
+        v = jax.random.normal(jax.random.PRNGKey(2), (2, 2, sq, 16))
+        ct = jax.random.normal(jax.random.PRNGKey(3), (2, 4, sq, 16))
+
+        def loss(fn):
+            return lambda q, k, v: jnp.sum(
+                fn(q, k, v, causal=causal, window=window) * ct)
+
+        got = jax.grad(loss(flash_attention), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(loss(blockwise_attention),
+                        argnums=(0, 1, 2))(q, k, v)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-5, atol=1e-5)
+
 
 class TestSSD:
     @pytest.mark.parametrize("s,chunk", [(64, 16), (50, 16), (128, 32),

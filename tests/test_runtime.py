@@ -67,6 +67,54 @@ class TestTrainerRestart:
         assert t.ckpt.latest_step() == 3
 
 
+class TestTrainerFailures:
+    def test_untyped_exception_propagates(self, tmp_path, mesh22):
+        """Only a RankFailure triggers elastic recovery: anything else (a
+        compile error, an OOM) fails the run instead of looping."""
+
+        class Boom:
+            calls = 0
+
+            def on_step(self, step, op):
+                Boom.calls += 1
+                raise ValueError("not a rank failure")
+
+        t = _trainer(tmp_path, mesh=mesh22)
+        t.fault_plan = Boom()
+        with pytest.raises(ValueError, match="not a rank failure"):
+            t.train()
+        assert Boom.calls == 1
+        assert t.elastic is None and t.history == []
+
+
+class TestCompileCache:
+    @pytest.fixture(autouse=True)
+    def _restore(self):
+        prev = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+    def test_env_dir_wins(self, monkeypatch, tmp_path):
+        from repro.launch.compile_cache import enable_compile_cache
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        before = jax.config.jax_compilation_cache_dir
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_checkout_root_otherwise(self, monkeypatch):
+        import pathlib
+
+        from repro.launch.compile_cache import enable_compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = pathlib.Path(__file__).resolve().parents[1]
+        path = enable_compile_cache()
+        assert path == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert enable_compile_cache() == path       # fixed across calls
+
+
 class TestWatchdog:
     def test_flags_stragglers(self, tmp_path, mesh22):
         t = _trainer(tmp_path, mesh=mesh22)
