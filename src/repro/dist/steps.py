@@ -585,6 +585,8 @@ def build_prefill_step(cfg: ModelConfig, mesh, scfg: StepConfig,
             jax.ShapeDtypeStruct((batch, n_tok, n_dim), jnp.float32))
         arg_specs.append(P(arg_specs[0][0], None, None))
 
+    # the program is ``jit_fwd`` in a profiler trace: keep the name, trace
+    # readers match it
     if with_frontend is None:
         def raw(params, tokens):
             return run(params, tokens)
@@ -687,6 +689,8 @@ def build_serve_step(cfg: ModelConfig, mesh, scfg: StepConfig,
     moe_runner = _moe_decode_runner(cfg, mesh, scfg.resolved_transport(),
                                     batch)
 
+    # the program is ``jit_fn`` in a profiler trace, and no other program
+    # the server runs has that name: keep it, trace readers match it
     def fn_(params, cache, tokens):
         cache, logits = decode_step(cfg, params, cache, tokens,
                                     moe_runner=moe_runner)
@@ -743,13 +747,13 @@ def build_prefill_chunk_step(cfg: ModelConfig, mesh, scfg: StepConfig,
     if with_frontend is not None:
         fe_spec = P(b_entry, None, None)
 
-        def fn_(params, scratch, tokens, frontend):
+        def prefill_chunk_step(params, scratch, tokens, frontend):
             with activation_sharding(constrain):
                 return prefill_chunk(cfg, params, scratch, tokens, lo,
                                      frontend_embeds=frontend)
 
         fn = jax.jit(
-            fn_,
+            prefill_chunk_step,
             in_shardings=(to_shardings(mesh, pspecs),
                           to_shardings(mesh, sspecs),
                           NamedSharding(mesh, tok_spec),
@@ -767,12 +771,12 @@ def build_prefill_chunk_step(cfg: ModelConfig, mesh, scfg: StepConfig,
                  "with_frontend": with_frontend},
         )
 
-    def fn_(params, scratch, tokens):
+    def prefill_chunk_step(params, scratch, tokens):
         with activation_sharding(constrain):
             return prefill_chunk(cfg, params, scratch, tokens, lo)
 
     fn = jax.jit(
-        fn_,
+        prefill_chunk_step,
         in_shardings=(to_shardings(mesh, pspecs),
                       to_shardings(mesh, sspecs),
                       NamedSharding(mesh, tok_spec)),
@@ -793,7 +797,8 @@ def build_slot_write_step(cfg: ModelConfig, mesh, batch: int,
     """``fn(cache, slot_cache, i) -> cache``: write a single-request cache
     (batch 1) into row ``i`` of every leaf of the batched decode cache —
     the per-slot admission PUT of the continuous-batching server.  The
-    batched cache is **donated**; only row ``i`` moves."""
+    batched cache is **donated**; only row ``i`` moves.  The program is
+    named ``jit_slot_write``."""
     full_shape = jax.eval_shape(lambda: init_cache(cfg, batch, max_seq))
     one_shape = jax.eval_shape(lambda: init_cache(cfg, 1, max_seq))
     # the batch axis of each leaf, found structurally (it differs per
@@ -807,7 +812,7 @@ def build_slot_write_step(cfg: ModelConfig, mesh, batch: int,
     cspecs = cache_pspecs(cfg, mesh, full_shape)
     sspecs = cache_pspecs(cfg, mesh, one_shape)
 
-    def fn_(cache, slot, i):
+    def slot_write(cache, slot, i):
         return {
             k: lax.dynamic_update_slice_in_dim(
                 cache[k], slot[k].astype(cache[k].dtype), i, axis=baxes[k])
@@ -815,7 +820,7 @@ def build_slot_write_step(cfg: ModelConfig, mesh, batch: int,
         }
 
     fn = jax.jit(
-        fn_,
+        slot_write,
         in_shardings=(to_shardings(mesh, cspecs),
                       to_shardings(mesh, sspecs), _scalar_sharding(mesh)),
         out_shardings=to_shardings(mesh, cspecs),
@@ -847,7 +852,7 @@ def build_block_write_step(cfg: ModelConfig, mesh, batch: int,
         lambda: init_paged_cache(cfg, batch, max_seq, block_size, n_blocks))
     cspecs = cache_pspecs(cfg, mesh, full_shape)
 
-    def fn_(cache, bk, bv, dst, table_row, slot_pos_row, pos, i):
+    def block_write(cache, bk, bv, dst, table_row, slot_pos_row, pos, i):
         out = dict(cache)
         out["kp"] = cache["kp"].at[:, dst].set(bk.astype(cache["kp"].dtype))
         out["vp"] = cache["vp"].at[:, dst].set(bv.astype(cache["vp"].dtype))
@@ -862,7 +867,7 @@ def build_block_write_step(cfg: ModelConfig, mesh, batch: int,
     # payload inputs keep whatever sharding prefill left them with (the
     # scatter re-lays them out); only the donated pool is pinned.
     fn = jax.jit(
-        fn_,
+        block_write,
         in_shardings=(to_shardings(mesh, cspecs),) + (None,) * 7,
         out_shardings=to_shardings(mesh, cspecs),
         donate_argnums=(0,))

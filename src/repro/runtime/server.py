@@ -44,7 +44,16 @@ tests/test_serving.py).
 TTFT accounting: ``Request.first_token`` is stamped when the request's
 first *decode token id* has actually been sampled and fetched — never at
 prefill completion — and stays correct under chunked admission because the
-stamp rides the token append, not the scheduler phase.
+stamp rides the token append, not the scheduler phase.  ``admitted`` (the
+request took a slot) and ``prefill_start`` (its bulk prefill, or first
+chunk, was dispatched) split the wait before it.
+
+Tracing: every phase of :meth:`Server.step` runs inside a named host span
+(``jax.profiler.TraceAnnotation``, the ``SPAN_*`` names below).  Under a
+running profiler the spans land in its trace on the clock of the device
+events, nested as the calls are; with no profiler each costs about a
+microsecond, so they are always on.  docs/serving.md ("Tracing a server")
+lists what each span covers.
 """
 
 from __future__ import annotations
@@ -84,6 +93,20 @@ from repro.models.prefill import (
     scratch_to_cache,
     seed_scratch_from_blocks,
 )
+
+_span = jax.profiler.TraceAnnotation
+
+#: host spans of the serving loop; a request's spans carry its ``rid``
+SPAN_SUBMIT = "serve.submit"            # Server.submit
+SPAN_STEP = "serve.step"                # the whole of Server.step
+SPAN_ADMIT = "serve.admit"              # queued requests take free slots
+#: prompt upload through the dispatch of the prefill (or chunk) and of the
+#: slot writer; args ``rid`` and ``tokens`` (positions prefilled)
+SPAN_PREFILL = "serve.prefill"
+SPAN_FIRST_TOKEN = "serve.first_token"  # blocking fetch of the first id
+SPAN_DECODE = "serve.decode"            # token upload + decode dispatch
+SPAN_FETCH = "serve.fetch"              # host waits for the decoded ids
+SPAN_EMIT = "serve.emit"                # per-slot append / retire
 
 
 class BlockPool:
@@ -347,6 +370,8 @@ class Request:
     frontend_embeds: Optional[np.ndarray] = None   # frontend (vlm) archs
     out_tokens: List[int] = dataclasses.field(default_factory=list)
     submitted: float = 0.0
+    admitted: Optional[float] = None        # took a slot
+    prefill_start: Optional[float] = None   # prefill (first chunk) dispatched
     first_token: Optional[float] = None
     finished: Optional[float] = None
     cancelled: bool = False
@@ -419,15 +444,20 @@ class Server:
         from repro.dist.sharding import to_shardings
         self._cache_sh = to_shardings(mesh, self.bundle.in_specs[1])
         self._slot_sh = to_shardings(mesh, self.writer.in_specs[1])
+        # each program the server runs has a name of its own (``jit_<def>``
+        # in a profiler trace); the decode step alone is ``jit_fn``
         if self._paged:
             blk, nb = self._blk, self._n_blocks
-            self.cache = jax.jit(
-                lambda: init_paged_cache(cfg, srv.max_batch, srv.max_seq,
-                                         blk, nb),
-                out_shardings=self._cache_sh)()
+
+            def cache_init():
+                return init_paged_cache(cfg, srv.max_batch, srv.max_seq,
+                                        blk, nb)
+
+            self.cache = jax.jit(cache_init,
+                                 out_shardings=self._cache_sh)()
             npb, sb = self._npb, self._sb
 
-            def _park(cache, i):
+            def park(cache, i):
                 out = dict(cache)
                 out["block_ids"] = lax.dynamic_update_slice_in_dim(
                     cache["block_ids"],
@@ -441,12 +471,14 @@ class Server:
                 return out
 
             self._park_fn = jax.jit(
-                _park, in_shardings=(self._cache_sh, None),
+                park, in_shardings=(self._cache_sh, None),
                 out_shardings=self._cache_sh, donate_argnums=(0,))
         else:
-            self.cache = jax.jit(
-                lambda: init_cache(cfg, srv.max_batch, srv.max_seq),
-                out_shardings=self._cache_sh)()
+            def cache_init():
+                return init_cache(cfg, srv.max_batch, srv.max_seq)
+
+            self.cache = jax.jit(cache_init,
+                                 out_shardings=self._cache_sh)()
         self._chunk_bundles: Dict[tuple, object] = {}   # (S, lo, C) -> bundle
         self._bulk_bundles: Dict[int, object] = {}      # S -> fn
         self._scratch_inits: Dict[int, object] = {}     # S -> jitted init
@@ -487,25 +519,26 @@ class Server:
 
     def submit(self, prompt: np.ndarray,
                frontend_embeds: Optional[np.ndarray] = None) -> int:
-        prompt = np.asarray(prompt, np.int32)
-        eff = self._eff_len(prompt.size)
-        assert prompt.ndim == 1 and 0 < eff <= self.srv.max_seq, (
-            prompt.shape, self.srv.max_seq)
-        if self.cfg.family == "encdec":
-            assert prompt.size <= self.cfg.decoder_max_seq, prompt.shape
-        if self.cfg.frontend:
-            assert frontend_embeds is not None, (
-                f"{self.cfg.name} requires frontend embeddings per request")
-            frontend_embeds = np.asarray(frontend_embeds, np.float32)
-            assert frontend_embeds.shape == (self.cfg.frontend_tokens,
-                                             self.cfg.frontend_dim), \
-                frontend_embeds.shape
         rid = len(self.queue) + len(self.done) + sum(s is not None
                                                      for s in self.slots)
-        req = Request(rid=rid, prompt=prompt,
-                      frontend_embeds=frontend_embeds,
-                      submitted=time.perf_counter())
-        self.queue.append(req)
+        with _span(SPAN_SUBMIT, rid=rid):
+            prompt = np.asarray(prompt, np.int32)
+            eff = self._eff_len(prompt.size)
+            assert prompt.ndim == 1 and 0 < eff <= self.srv.max_seq, (
+                prompt.shape, self.srv.max_seq)
+            if self.cfg.family == "encdec":
+                assert prompt.size <= self.cfg.decoder_max_seq, prompt.shape
+            if self.cfg.frontend:
+                assert frontend_embeds is not None, (
+                    f"{self.cfg.name} requires frontend embeddings per "
+                    f"request")
+                frontend_embeds = np.asarray(frontend_embeds, np.float32)
+                assert frontend_embeds.shape == (self.cfg.frontend_tokens,
+                                                 self.cfg.frontend_dim), \
+                    frontend_embeds.shape
+            self.queue.append(Request(rid=rid, prompt=prompt,
+                                      frontend_embeds=frontend_embeds,
+                                      submitted=time.perf_counter()))
         return rid
 
     def _admit(self):
@@ -523,6 +556,9 @@ class Server:
                 if self._paged and not self._claim_blocks(req):
                     break
                 self.queue.pop(0)
+                if req.admitted is None:
+                    # a recovered request keeps its first stamps
+                    req.admitted = time.perf_counter()
                 req.phase = "prefill"
                 req._cursor = 0
                 if self._chunkable:
@@ -617,13 +653,13 @@ class Server:
             cfg = self.cfg
             ssh = self._scratch_specs(se)
 
-            def _seed(scratch, cache, bids):
+            def seed_scratch(scratch, cache, bids):
                 bk = jnp.take(cache["kp"], bids, axis=1)
                 bv = jnp.take(cache["vp"], bids, axis=1)
                 return seed_scratch_from_blocks(cfg, scratch, bk, bv)
 
             self._seed_fns[key] = jax.jit(
-                _seed, in_shardings=(ssh, self._cache_sh, None),
+                seed_scratch, in_shardings=(ssh, self._cache_sh, None),
                 out_shardings=ssh, donate_argnums=(0,))
         return self._seed_fns[key]
 
@@ -631,10 +667,12 @@ class Server:
         """Jitted scratch→pool-blocks conversion (the paged finish)."""
         if s not in self._blocks_fns:
             cfg, max_seq, blk = self.cfg, self.srv.max_seq, self._blk
-            self._blocks_fns[s] = jax.jit(
-                lambda scr: scratch_to_blocks(cfg, scr, blk,
-                                              cache_len=max_seq),
-                donate_argnums=(0,))
+
+            def scratch_to_pool(scr):
+                return scratch_to_blocks(cfg, scr, blk, cache_len=max_seq)
+
+            self._blocks_fns[s] = jax.jit(scratch_to_pool,
+                                          donate_argnums=(0,))
         return self._blocks_fns[s]
 
     def _block_writer(self, n_write: int):
@@ -682,9 +720,12 @@ class Server:
         """Jitted scratch allocator, sharded like the chunk step's input."""
         if se not in self._scratch_inits:
             cfg = self.cfg
+
+            def scratch_init():
+                return init_prefill_scratch(cfg, 1, se)
+
             self._scratch_inits[se] = jax.jit(
-                lambda: init_prefill_scratch(cfg, 1, se),
-                out_shardings=self._scratch_specs(se))
+                scratch_init, out_shardings=self._scratch_specs(se))
         return self._scratch_inits[se]
 
     def _bulk_fn(self, s: int):
@@ -701,9 +742,12 @@ class Server:
         writer's slot-cache input."""
         if s not in self._finish_fns:
             cfg, max_seq = self.cfg, self.srv.max_seq
-            self._finish_fns[s] = jax.jit(
-                lambda scr: scratch_to_cache(cfg, scr, cache_len=max_seq),
-                out_shardings=self._slot_sh)
+
+            def scratch_to_ring(scr):
+                return scratch_to_cache(cfg, scr, cache_len=max_seq)
+
+            self._finish_fns[s] = jax.jit(scratch_to_ring,
+                                          out_shardings=self._slot_sh)
         return self._finish_fns[s]
 
     def _emit_first_token(self, i: int, req: Request, logits):
@@ -711,7 +755,8 @@ class Server:
         logits and move the slot to the decode phase.  ``first_token`` is
         stamped *here* — after the id has been computed and fetched, i.e.
         at the first decode token, not at prefill completion."""
-        tok = int(jnp.argmax(logits[0], axis=-1))
+        with _span(SPAN_FIRST_TOKEN, rid=req.rid):
+            tok = int(jnp.argmax(logits[0], axis=-1))
         if req.first_token is None:
             # a re-admitted (recovered) request already stamped TTFT on
             # its genuine first token, pre-failure
@@ -732,63 +777,68 @@ class Server:
         if not pending:
             return
         _, i, req = min(pending)
+        if req.prefill_start is None:
+            # a recovered request keeps its first stamps
+            req.prefill_start = time.perf_counter()
         s = int(req.prompt.size)
-        toks = jnp.asarray(req.prompt[None, :])
+        se = self._eff_len(s)
 
         if not self._chunkable:
-            args = (self.params, toks)
-            if self.cfg.frontend:
-                args += (jnp.asarray(req.frontend_embeds[None, :]),)
-            cache1, logits = self._bulk_fn(s)(*args)
-            if self._paged:
-                self._install_paged(i, req,
-                                    cache_to_blocks(self.cfg, cache1,
-                                                    self._blk))
-            else:
-                self.cache = self.writer.fn(self.cache, cache1,
-                                            jnp.int32(i))
+            with _span(SPAN_PREFILL, rid=req.rid, tokens=se):
+                args = (self.params, jnp.asarray(req.prompt[None, :]))
+                if self.cfg.frontend:
+                    args += (jnp.asarray(req.frontend_embeds[None, :]),)
+                cache1, logits = self._bulk_fn(s)(*args)
+                if self._paged:
+                    self._install_paged(i, req,
+                                        cache_to_blocks(self.cfg, cache1,
+                                                        self._blk))
+                else:
+                    self.cache = self.writer.fn(self.cache, cache1,
+                                                jnp.int32(i))
             self._emit_first_token(i, req, logits)
             return
 
-        se = self._eff_len(s)
         cuts = prefill_chunk_cuts(se, chunk_len=self._eff_chunk)
         lo, hi = cuts[req._cursor]
-        cfg = self.cfg
-        if cfg.family == "encdec":
-            # frames feed the encoder exactly once, on chunk 0
-            n_fe = cfg.frontend_tokens if lo == 0 else None
-            fe = (jnp.asarray(req.frontend_embeds[None, :])
-                  if lo == 0 else None)
-            tok_slice = toks[:, lo:hi]
-        elif cfg.frontend:
-            # vlm: frontend rows prefix the token rows of the scratch —
-            # slice each exactly as the bulk concat lays them out
-            ft = cfg.frontend_tokens
-            n_fe = max(0, min(hi, ft) - lo) if lo < ft else None
-            fe = (jnp.asarray(req.frontend_embeds[None, lo:min(hi, ft)])
-                  if n_fe else None)
-            if n_fe == 0:
-                n_fe = None
-            tok_slice = toks[:, max(0, lo - ft):max(0, hi - ft)]
-        else:
-            n_fe, fe = None, None
-            tok_slice = toks[:, lo:hi]
-        fn = self._chunk_bundle(se, lo, hi - lo, n_fe).fn
-        args = (self.params, req._scratch, tok_slice)
-        if n_fe is not None:
-            args += (fe,)
-        req._scratch, logits = fn(*args)
-        req._cursor += 1
-        if req._cursor < len(cuts):
-            return                          # more chunks; decode proceeds
-        if self._paged:
-            blocks = self._blocks_fn(se)(req._scratch)
-            req._scratch = None
-            self._install_paged(i, req, blocks)
-        else:
-            cache1 = self._finish_fn(se)(req._scratch)
-            req._scratch = None
-            self.cache = self.writer.fn(self.cache, cache1, jnp.int32(i))
+        with _span(SPAN_PREFILL, rid=req.rid, tokens=hi - lo):
+            toks = jnp.asarray(req.prompt[None, :])
+            cfg = self.cfg
+            if cfg.family == "encdec":
+                # frames feed the encoder exactly once, on chunk 0
+                n_fe = cfg.frontend_tokens if lo == 0 else None
+                fe = (jnp.asarray(req.frontend_embeds[None, :])
+                      if lo == 0 else None)
+                tok_slice = toks[:, lo:hi]
+            elif cfg.frontend:
+                # vlm: frontend rows prefix the token rows of the scratch —
+                # slice each exactly as the bulk concat lays them out
+                ft = cfg.frontend_tokens
+                n_fe = max(0, min(hi, ft) - lo) if lo < ft else None
+                fe = (jnp.asarray(req.frontend_embeds[None, lo:min(hi, ft)])
+                      if n_fe else None)
+                if n_fe == 0:
+                    n_fe = None
+                tok_slice = toks[:, max(0, lo - ft):max(0, hi - ft)]
+            else:
+                n_fe, fe = None, None
+                tok_slice = toks[:, lo:hi]
+            fn = self._chunk_bundle(se, lo, hi - lo, n_fe).fn
+            args = (self.params, req._scratch, tok_slice)
+            if n_fe is not None:
+                args += (fe,)
+            req._scratch, logits = fn(*args)
+            req._cursor += 1
+            if req._cursor < len(cuts):
+                return                      # more chunks; decode proceeds
+            if self._paged:
+                blocks = self._blocks_fn(se)(req._scratch)
+                req._scratch = None
+                self._install_paged(i, req, blocks)
+            else:
+                cache1 = self._finish_fn(se)(req._scratch)
+                req._scratch = None
+                self.cache = self.writer.fn(self.cache, cache1, jnp.int32(i))
         self._emit_first_token(i, req, logits)
 
     def _retire(self, i: int, req: Request,
@@ -937,41 +987,47 @@ class Server:
         :class:`~repro.runtime.membership.MembershipEvent` drives
         :meth:`fail_decode_ranks` (one call per epoch bump, however many
         ranks died) and :meth:`admit_decode_rank` (scale-out joins)."""
-        self._ticks += 1
-        if self.membership is not None:
-            ev = self.membership.on_step(self._ticks)
-            if ev is not None:
-                n = self.membership.n_ranks
-                if ev.died:
-                    self.fail_decode_ranks(ev.died, n_ranks=n)
-                for r in ev.joined:
-                    self.admit_decode_rank(r, n_ranks=n)
-        elif self.fault_plan is not None:
-            from repro.core.conduit import RankFailure
-            try:
-                self.fault_plan.on_step(self._ticks, "serve_step")
-            except RankFailure as e:
-                dead = e.rank if e.rank is not None else 0
-                self.fault_plan.repair(dead)
-                self.fail_decode_rank(dead)
-        self._admit()
-        self._prefill_tick()
-        if not any(r is not None and r.phase == "decode"
-                   for r in self.slots):
-            return
-        toks = jnp.asarray(self._next_tok)
-        self.cache, ids = self.bundle.fn(self.params, self.cache, toks)
-        choice = np.asarray(ids)            # ONE stacked host transfer
-        now = time.perf_counter()
-        for i, req in enumerate(self.slots):
-            if req is None or req.phase != "decode":
-                continue
-            tok = int(choice[i])
-            req.out_tokens.append(tok)
-            self._next_tok[i] = tok
-            if (len(req.out_tokens) >= self.srv.max_new_tokens
-                    or tok == self.srv.eos_id):
-                self._retire(i, req, now)
+        with _span(SPAN_STEP):
+            self._ticks += 1
+            if self.membership is not None:
+                ev = self.membership.on_step(self._ticks)
+                if ev is not None:
+                    n = self.membership.n_ranks
+                    if ev.died:
+                        self.fail_decode_ranks(ev.died, n_ranks=n)
+                    for r in ev.joined:
+                        self.admit_decode_rank(r, n_ranks=n)
+            elif self.fault_plan is not None:
+                from repro.core.conduit import RankFailure
+                try:
+                    self.fault_plan.on_step(self._ticks, "serve_step")
+                except RankFailure as e:
+                    dead = e.rank if e.rank is not None else 0
+                    self.fault_plan.repair(dead)
+                    self.fail_decode_rank(dead)
+            with _span(SPAN_ADMIT):
+                self._admit()
+            self._prefill_tick()
+            if not any(r is not None and r.phase == "decode"
+                       for r in self.slots):
+                return
+            with _span(SPAN_DECODE):
+                toks = jnp.asarray(self._next_tok)
+                self.cache, ids = self.bundle.fn(self.params, self.cache,
+                                                 toks)
+            with _span(SPAN_FETCH):
+                choice = np.asarray(ids)    # ONE stacked host transfer
+            now = time.perf_counter()
+            with _span(SPAN_EMIT):
+                for i, req in enumerate(self.slots):
+                    if req is None or req.phase != "decode":
+                        continue
+                    tok = int(choice[i])
+                    req.out_tokens.append(tok)
+                    self._next_tok[i] = tok
+                    if (len(req.out_tokens) >= self.srv.max_new_tokens
+                            or tok == self.srv.eos_id):
+                        self._retire(i, req, now)
 
     def run(self, max_steps: int = 10_000):
         steps = 0
