@@ -651,9 +651,12 @@ def build_serve_step(cfg: ModelConfig, mesh, scfg: StepConfig,
     batched decode step against the ring-buffer cache (continuous-batching
     inner loop; every cache row advances at its own per-slot position).
 
-    The cache is **donated** — in/out shardings match leaf-for-leaf, so on
-    backends with donation the step updates the ring buffers in place
-    instead of copying the whole cache every token.
+    The cache is **donated** — in/out shardings match leaf-for-leaf, so
+    the output cache takes the input's memory.  On the contiguous GQA
+    ring the step writes each row's new K/V vector per layer in place
+    and copies no slab of the ring (``models/decode.decode_step``); the
+    paged, MLA, encdec and hybrid caches still pass through the layer
+    scan whole.
 
     ``sample=True`` returns greedy-sampled ``(B,)`` int32 token ids instead
     of the (B, V) logits: argmax runs on device and the server fetches one

@@ -14,7 +14,11 @@ Cache design notes (these drive the decode-shape roofline memory term):
   4096-token sliding window the long_500k cache is 4096 slots, not 500k
   (the reason the arch runs that shape at all).  A per-row ``slot_pos``
   array maps buffer slots to absolute positions; masking validates
-  ``pos - window < slot_pos <= pos``.
+  ``pos - window < slot_pos <= pos``.  The decode step carries the
+  stacked (L, B, Hkv, S_buf, hd) ring through its layer loop and, per
+  layer, writes each row's one new K/V vector in place before reading the
+  layer's slab for attention: with the cache donated, the step moves the
+  ring once (the attention's read) and copies none of it.
 * MLA (minicpm3): caches the 256-d latent + 32-d shared rope key instead of
   per-head K/V, and uses the *absorbed* formulation (W_uk folded into the
   query, W_uv into the output) so per-token work is O(S_buf · r).
@@ -253,6 +257,21 @@ def _row_update(buf, new, slot):
     return jax.vmap(one)(buf, new.astype(buf.dtype), slot)
 
 
+def _ring_write(ring, new, layer, slot):
+    """Write each row's new vector ``new`` (B, Hkv, hd) into the stacked
+    ring (L, B, Hkv, S_buf, hd) at ``[layer, b, :, slot[b], :]``, in place.
+
+    One dynamic-update-slice per row: a scatter would make the TPU
+    compiler lay the whole ring out again with (Hkv, hd) minor (padded
+    3.2x, copied in and out of the loop), while an update slice writes
+    into the S-minor layout the ring keeps."""
+    new = new.astype(ring.dtype)
+    for r in range(new.shape[0]):
+        ring = lax.dynamic_update_slice(
+            ring, new[r][None, None, :, None, :], (layer, r, 0, slot[r], 0))
+    return ring
+
+
 def _masked_softmax_attend(scores, vcache, slot_pos, pos, window):
     """scores: (B, Hkv, G, S_buf) fp32; vcache: (B, Hkv, S_buf, hd);
     ``slot_pos`` (B, S_buf) / ``pos`` (B,) are per batch row."""
@@ -265,17 +284,13 @@ def _masked_softmax_attend(scores, vcache, slot_pos, pos, window):
     return jnp.einsum("bkgs,bksd->bkgd", p, vcache.astype(jnp.float32))
 
 
-def attention_decode(cfg: ModelConfig, p: Params, x: jnp.ndarray,
-                     kc: jnp.ndarray, vc: jnp.ndarray,
-                     slot_pos_new: jnp.ndarray, pos: jnp.ndarray,
-                     rope: bool = True, window: Optional[int] = None):
-    """x: (B, D) single token; ``pos`` (B,) per-row.  Returns
-    (out (B, D), kc, vc)."""
+def _decode_qkv(cfg: ModelConfig, p: Params, x: jnp.ndarray,
+                pos: jnp.ndarray, rope: bool = True):
+    """The new token's projections: q (B, Hq, hd), k/v (B, Hkv, hd) in
+    the compute dtype, rotated to the per-row ``pos`` (B,)."""
     b, _ = x.shape
     hd = cfg.resolved_head_dim
     hkv, hq = cfg.n_kv_heads, cfg.n_heads
-    g = hq // hkv
-    sb = kc.shape[2]
     cd = _cd(cfg)
     xc = x.astype(cd)
 
@@ -286,15 +301,40 @@ def attention_decode(cfg: ModelConfig, p: Params, x: jnp.ndarray,
         posv = pos[:, None, None]
         q = L.apply_rope(q[:, :, None, :], posv, cfg.rope_theta)[:, :, 0]
         k = L.apply_rope(k[:, :, None, :], posv, cfg.rope_theta)[:, :, 0]
+    return q, k, v
 
-    slot = pos % sb
-    kc = _row_update(kc, k[:, :, None, :], slot)
-    vc = _row_update(vc, v[:, :, None, :], slot)
+
+def _decode_attend(cfg: ModelConfig, p: Params, q: jnp.ndarray,
+                   kc: jnp.ndarray, vc: jnp.ndarray,
+                   slot_pos_new: jnp.ndarray, pos: jnp.ndarray,
+                   window: Optional[int]):
+    """q (B, Hq, hd) against one layer's ring kc/vc (B, Hkv, S_buf, hd),
+    which already holds the new row; returns the output projection (B, D)
+    in the compute dtype."""
+    b = q.shape[0]
+    hd = cfg.resolved_head_dim
+    hkv, hq = cfg.n_kv_heads, cfg.n_heads
+    g = hq // hkv
+    cd = _cd(cfg)
     qg = q.reshape(b, hkv, g, hd).astype(jnp.float32) * hd ** -0.5
     scores = jnp.einsum("bkgd,bksd->bkgs", qg, kc.astype(jnp.float32))
     out = _masked_softmax_attend(scores, vc, slot_pos_new, pos, window)
     out = out.reshape(b, hq * hd).astype(cd)
-    return (out @ p["wo"].astype(cd)).astype(x.dtype), kc, vc
+    return out @ p["wo"].astype(cd)
+
+
+def attention_decode(cfg: ModelConfig, p: Params, x: jnp.ndarray,
+                     kc: jnp.ndarray, vc: jnp.ndarray,
+                     slot_pos_new: jnp.ndarray, pos: jnp.ndarray,
+                     rope: bool = True, window: Optional[int] = None):
+    """x: (B, D) single token; ``pos`` (B,) per-row; kc/vc one layer's
+    ring (B, Hkv, S_buf, hd).  Returns (out (B, D), kc, vc)."""
+    q, k, v = _decode_qkv(cfg, p, x, pos, rope)
+    slot = pos % kc.shape[2]
+    kc = _row_update(kc, k[:, :, None, :], slot)
+    vc = _row_update(vc, v[:, :, None, :], slot)
+    out = _decode_attend(cfg, p, q, kc, vc, slot_pos_new, pos, window)
+    return out.astype(x.dtype), kc, vc
 
 
 def cross_attention_decode(cfg, p, x, kc, vc, n_valid: int):
@@ -459,18 +499,27 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Cache,
             cache = dict(cache, kp=kps, vp=vps, slot_pos=slot_pos_new,
                          pos=pos + 1)
         else:
-            def body(h, layer):
-                lp, kc, vc = layer
-                normed = L.apply_norm(cfg, lp["ln1"], h)
-                a, kc, vc = attention_decode(cfg, lp["attn"], normed, kc, vc,
-                                             slot_pos_new, pos,
-                                             window=cfg.window)
-                h = h + a
-                f = ffn(L.apply_norm(cfg, lp["ln2"], h), lp)
-                return h + f, (kc, vc)
+            # the stacked (L, B, Hkv, S_buf, hd) ring rides the loop carry:
+            # each layer first writes every row's new K/V vector in place
+            # at [l, b, :, pos_b % S_buf], then reads its updated slab for
+            # attention, so no slab or ring is copied
+            slot = pos % cache["k"].shape[3]
 
-            x, (ks, vs) = lax.scan(
-                body, x, (params["layers"], cache["k"], cache["v"]))
+            def body(carry, lp):
+                h, ks, vs, l = carry
+                normed = L.apply_norm(cfg, lp["ln1"], h)
+                q, k, v = _decode_qkv(cfg, lp["attn"], normed, pos)
+                ks = _ring_write(ks, k, l, slot)
+                vs = _ring_write(vs, v, l, slot)
+                a = _decode_attend(cfg, lp["attn"], q, ks[l], vs[l],
+                                   slot_pos_new, pos, cfg.window)
+                h = h + a.astype(normed.dtype)
+                f = ffn(L.apply_norm(cfg, lp["ln2"], h), lp)
+                return (h + f, ks, vs, l + 1), None
+
+            (x, ks, vs, _), _ = lax.scan(
+                body, (x, cache["k"], cache["v"], jnp.int32(0)),
+                params["layers"])
             cache = dict(cache, k=ks, v=vs, slot_pos=slot_pos_new,
                          pos=pos + 1)
 

@@ -275,6 +275,127 @@ class TestPerSlotDecode:
             assert int(toks[1]) == int(jnp.argmax(lb1, -1)[0])
 
 
+def _xs_ys_decode_step(cfg, params, cache, tokens):
+    """The decode step as it was before the ring rode the loop carry,
+    kept as the reference: each layer's ring slab goes through the layer
+    scan as ``xs``, takes the new rows by a vmapped update slice, and
+    comes back as ``ys``.  Dense GQA with a contiguous ring only."""
+    from jax import lax
+
+    from repro.models import layers as L
+    from repro.models.decode import _masked_softmax_attend
+    from repro.models.model import _lm_logits
+
+    pos = cache["pos"]
+    b = tokens.shape[0]
+    hd, hkv, hq = cfg.resolved_head_dim, cfg.n_kv_heads, cfg.n_heads
+    cd = jnp.dtype(cfg.compute_dtype)
+    sb = cache["slot_pos"].shape[1]
+    slot_pos_new = cache["slot_pos"].at[jnp.arange(b), pos % sb].set(pos)
+
+    def row_update(buf, new, slot):
+        def one(bb, n, s):
+            return lax.dynamic_update_slice(bb, n, (0, s, 0))
+        return jax.vmap(one)(buf, new.astype(buf.dtype), slot)
+
+    def attention(p, x, kc, vc):
+        xc = x.astype(cd)
+        q = (xc @ p["wq"].astype(cd)).reshape(b, hq, hd)
+        k = (xc @ p["wk"].astype(cd)).reshape(b, hkv, hd)
+        v = (xc @ p["wv"].astype(cd)).reshape(b, hkv, hd)
+        posv = pos[:, None, None]
+        q = L.apply_rope(q[:, :, None, :], posv, cfg.rope_theta)[:, :, 0]
+        k = L.apply_rope(k[:, :, None, :], posv, cfg.rope_theta)[:, :, 0]
+        kc = row_update(kc, k[:, :, None, :], pos % sb)
+        vc = row_update(vc, v[:, :, None, :], pos % sb)
+        qg = q.reshape(b, hkv, hq // hkv, hd).astype(jnp.float32) * hd ** -0.5
+        scores = jnp.einsum("bkgd,bksd->bkgs", qg, kc.astype(jnp.float32))
+        out = _masked_softmax_attend(scores, vc, slot_pos_new, pos,
+                                     cfg.window)
+        out = out.reshape(b, hq * hd).astype(cd)
+        return (out @ p["wo"].astype(cd)).astype(x.dtype), kc, vc
+
+    def body(h, layer):
+        lp, kc, vc = layer
+        a, kc, vc = attention(lp["attn"], L.apply_norm(cfg, lp["ln1"], h),
+                              kc, vc)
+        h = h + a
+        return h + L.mlp(cfg, lp["mlp"], L.apply_norm(cfg, lp["ln2"], h)), \
+            (kc, vc)
+
+    x = jnp.take(params["embed"], tokens, axis=0)
+    x, (ks, vs) = lax.scan(body, x, (params["layers"], cache["k"],
+                                     cache["v"]))
+    x = L.apply_norm(cfg, params["final_norm"], x)
+    logits = _lm_logits(cfg, params, x[:, None, :])[:, 0]
+    return dict(cache, k=ks, v=vs, slot_pos=slot_pos_new, pos=pos + 1), \
+        logits
+
+
+class TestInPlaceRingDecode:
+    """The contiguous GQA decode step writes each row's one new K/V
+    vector per layer into the ring it carries, and leaves every other
+    entry alone; bit-identical to the ``xs``/``ys`` formulation."""
+
+    @staticmethod
+    def _filled_cache(cfg, positions, max_seq, key):
+        """A ring full of random K/V whose ``slot_pos`` holds, per row,
+        the last ``S_buf`` positions before that row's ``pos``."""
+        b = len(positions)
+        cache = init_cache(cfg, b, max_seq)
+        sb = cache["slot_pos"].shape[1]
+        kk, kv = jax.random.split(jax.random.PRNGKey(key))
+        cache["k"] = jax.random.normal(kk, cache["k"].shape).astype(
+            cache["k"].dtype)
+        cache["v"] = jax.random.normal(kv, cache["v"].shape).astype(
+            cache["v"].dtype)
+        slot_pos = np.full((b, sb), -1, np.int32)
+        for r, p in enumerate(positions):
+            for q in range(max(0, p - sb), p):
+                slot_pos[r, q % sb] = q
+        cache["slot_pos"] = jnp.asarray(slot_pos)
+        cache["pos"] = jnp.asarray(positions, jnp.int32)
+        return cache
+
+    # rows at different positions: fresh, mid-ring, at the ring's last
+    # slot, and wrapped past it (old entries overwritten in place)
+    @pytest.mark.parametrize("name,positions,overrides", [
+        ("smollm-360m", [0, 5, 15, 21], {}),
+        ("smollm-360m", [1, 9, 16, 30],
+         {"param_dtype": "bfloat16", "compute_dtype": "bfloat16"}),
+        ("h2o-danube-1.8b", [2, 7, 8, 13], {}),
+    ])
+    def test_only_new_slots_change_and_equal_xs_ys(self, name, positions,
+                                                   overrides):
+        cfg, params = _setup(name, **overrides)
+        max_seq = 16
+        cache = self._filled_cache(cfg, positions, max_seq, key=11)
+        sb = cache["slot_pos"].shape[1]
+        assert any(p >= sb for p in positions)  # one row wraps the ring
+        toks = jnp.asarray([3, 17, 42, 99], jnp.int32)
+        before = jax.tree.map(np.asarray, cache)
+
+        ref_cache, ref_logits = jax.jit(
+            lambda c, t: _xs_ys_decode_step(cfg, params, c, t))(cache, toks)
+        new_cache, logits = jax.jit(
+            lambda c, t: decode_step(cfg, params, c, t))(cache, toks)
+
+        np.testing.assert_array_equal(np.asarray(logits),
+                                      np.asarray(ref_logits))
+        _assert_tree_equal(new_cache, ref_cache, "carry vs xs/ys")
+        for leaf in ("k", "v"):
+            got = np.asarray(new_cache[leaf])
+            changed = np.zeros(got.shape[:2] + (sb,), bool)
+            changed[:, np.arange(len(positions)),
+                    np.asarray(positions) % sb] = True
+            keep = ~changed[:, :, None, :, None].repeat(
+                got.shape[2], 2).repeat(got.shape[4], 4)
+            np.testing.assert_array_equal(got[keep], before[leaf][keep],
+                                          err_msg=f"{leaf} outside new slots")
+            # the new slot of every row and layer did take the new vector
+            assert (got[~keep] != before[leaf][~keep]).any(), leaf
+
+
 EP_TRANSPORTS = ("ring", "bidir", "auto")
 
 

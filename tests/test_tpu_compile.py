@@ -11,6 +11,7 @@ the TPU compiler; where it cannot be described every test skips.
 
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -166,3 +167,42 @@ def test_sharded_attention_1x4(topo, monkeypatch):
     _compile(functools.partial(runner, causal=True, window=cfg.window,
                                scale=None),
              *_attn_shapes(cfg, act, 2, 2048))
+
+
+def test_decode_step_updates_ring_in_place_smollm(topo):
+    """The serving cell's decode step at 64 slots x 2048: the K/V ring
+    rides the layer loop in place, so the program holds no second ring in
+    temporaries and copies no layer's slab nor the whole ring."""
+    import re
+
+    from repro.dist import steps
+    from repro.dist.sharding import to_shardings
+
+    cfg = get_config("smollm-360m")
+    batch, max_seq = 64, 2048
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    bundle = steps.build_serve_step(cfg, mesh, steps.StepConfig(),
+                                    batch=batch, max_seq=max_seq,
+                                    sample=True)
+
+    def sds(shapes, specs):
+        return jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            shapes, to_shardings(mesh, specs))
+
+    cache_shape = bundle.aux["cache_shape"]
+    compiled = bundle.fn.lower(
+        sds(bundle.aux["params_shape"], bundle.in_specs[0]),
+        sds(cache_shape, bundle.in_specs[1]),
+        jax.ShapeDtypeStruct((batch,), jnp.int32)).compile()
+
+    kv_bytes = sum(cache_shape[k].size * cache_shape[k].dtype.itemsize
+                   for k in ("k", "v"))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < kv_bytes / 8, (temp, kv_bytes)
+
+    ring = cache_shape["k"].shape                  # (L, B, Hkv, S_buf, hd)
+    whole = (sorted(ring), sorted(ring[1:]))       # ring, one layer's slab
+    for m in re.finditer(r"= \w+\[([\d,]*)\]\S* copy\(", compiled.as_text()):
+        dims = [int(d) for d in m.group(1).split(",") if d and d != "1"]
+        assert sorted(dims) not in whole, m.group(0)
