@@ -1,5 +1,7 @@
 """What the benchmark takes from the program: its model configuration,
-built from a configuration file, and the shapes of its parameters.
+built from a configuration file, and the shapes of its parameters.  The
+mapping from the file to the program's fields is the family's
+(``chipbench/families/<model_type>.py``).
 
 The configuration file is what runs: every published number in it
 overrides the registry entry of the same architecture, and the harness
@@ -11,33 +13,16 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict
 
-#: configuration-file key -> ModelConfig field
-FIELDS = {
-    "num_hidden_layers": "n_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size",
-    "head_dim": "head_dim",
-    "sliding_window": "window",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps",
-    "tie_word_embeddings": "tie_embeddings",
-    "hidden_act": "activation",
-    "torch_dtype": "param_dtype",
-}
+from chipbench import families
 
 
 def model_config(hf: Dict):
-    """The program's ``ModelConfig`` for the configuration file ``hf``."""
+    """The program's ``ModelConfig`` for the configuration file ``hf``: the
+    registry entry of ``hf["arch"]`` with the fields its family sets."""
     from repro.configs import get_config
 
-    cfg = get_config(hf["arch"])
-    changes = {field: hf[key] for key, field in FIELDS.items()}
-    changes["compute_dtype"] = hf["torch_dtype"]
-    changes["gated_mlp"] = True
-    return dataclasses.replace(cfg, **changes)
+    changes = families.load(hf).program_changes(hf)
+    return dataclasses.replace(get_config(hf["arch"]), **changes)
 
 
 def check_param_shapes(cfg, hf: Dict) -> None:
