@@ -19,6 +19,7 @@ _MODULES: Dict[str, str] = {
     "internvl2-2b": "internvl2_2b",
     "nemotron-4-340b": "nemotron_4_340b",
     "minicpm3-4b": "minicpm3_4b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "h2o-danube-1.8b": "h2o_danube_1p8b",
     "smollm-360m": "smollm_360m",
     "mamba2-2.7b": "mamba2_2p7b",

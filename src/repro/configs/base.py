@@ -29,7 +29,7 @@ class ModelConfig:
     # -- attention variant ---------------------------------------------------
     attn_type: str = "gqa"          # gqa | mla | none
     window: Optional[int] = None    # sliding-window attention (h2o-danube)
-    # MLA (minicpm3)
+    # MLA (minicpm3, deepseek-v2-lite); q_lora_rank 0 = a direct w_q
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_rope_dim: int = 0
@@ -41,11 +41,20 @@ class ModelConfig:
     gated_mlp: bool = True
 
     # -- MoE -------------------------------------------------------------------
-    n_experts: int = 0
+    n_experts: int = 0              # the router's outputs
     experts_per_token: int = 0
     n_shared_experts: int = 0
     moe_layer_period: int = 1       # 1 => every layer is MoE
-    capacity_factor: float = 1.25
+    capacity_factor: Optional[float] = 1.25   # None: dropless routing
+    norm_topk_prob: bool = True     # renormalise the top-k weights to 1
+    # the experts this chip holds: experts [expert_offset, +experts_held)
+    # of the router's n_experts (0 = all; the expert-parallel share)
+    experts_held: int = 0
+    expert_offset: int = 0
+    # leading dense layers of a MoE stack (deepseek first_k_dense_replace)
+    # and their MLP width (0 = d_ff, the expert width)
+    first_dense_layers: int = 0
+    dense_d_ff: int = 0
 
     # -- SSM (mamba2 / zamba2) --------------------------------------------------
     ssm_state: int = 0
@@ -73,6 +82,12 @@ class ModelConfig:
 
     # -- common ------------------------------------------------------------------
     rope_theta: float = 10000.0
+    # YaRN rope scaling as sorted (key, value) pairs of the published
+    # ``rope_scaling`` (factor, original_max_position_embeddings,
+    # beta_fast, beta_slow, mscale, mscale_all_dim); None = plain rope
+    rope_scaling: Optional[Tuple[Tuple[str, float], ...]] = None
+    # rotate (2i, 2i+1) pairs rather than halves (deepseek's de-interleave)
+    rope_interleave: bool = False
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
 
@@ -103,6 +118,15 @@ class ModelConfig:
     def supports_long_context(self) -> bool:
         """Sub-quadratic sequence scaling: SSM/hybrid state or SWA window."""
         return self.family in ("ssm", "hybrid") or self.window is not None
+
+    @property
+    def n_local_experts(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.first_dense_layers
 
     @property
     def d_inner(self) -> int:
@@ -138,12 +162,20 @@ class ModelConfig:
             "remat": "none",
         }
         if self.attn_type == "mla":
-            r.update(q_lora_rank=32, kv_lora_rank=16, qk_rope_dim=8,
-                     qk_nope_dim=8, v_head_dim=16, head_dim=16)
+            r.update(q_lora_rank=32 if self.q_lora_rank else 0,
+                     kv_lora_rank=16, qk_rope_dim=8, qk_nope_dim=8,
+                     v_head_dim=16, head_dim=16)
         if self.window is not None:
             r["window"] = 8
         if self.n_experts:
             r.update(n_experts=4, experts_per_token=min(self.experts_per_token, 2))
+            if self.n_experts > 16:
+                # a wide router keeps 8 outputs, of which the miniature
+                # holds 4: the share one chip of an expert-parallel
+                # deployment holds
+                r.update(n_experts=8, experts_held=4, expert_offset=0)
+        if self.first_dense_layers:
+            r.update(n_layers=self.first_dense_layers + 2, dense_d_ff=192)
         if self.family in ("ssm", "hybrid"):
             r.update(ssm_state=16, ssm_heads=4, ssm_head_dim=16, ssm_chunk=8,
                      ssm_groups=1)
@@ -184,8 +216,9 @@ class ChunkCarrySpec:
       then decoder ring rows (whisper).
 
     ``exact`` is the bit-identity claim: chunked ≡ bulk prefill, bit for
-    bit.  MoE is the one documented exception (``exact=False``): expert
-    capacity is bookkept per chunk, so the drop set may differ from bulk's
+    bit.  MoE with a capacity is the one documented exception
+    (``exact=False``; dropless routing, ``capacity_factor=None``, is
+    exact): expert capacity is bookkept per chunk, so the drop set may differ from bulk's
     — each MoE layer's output agrees bitwise at every token whose
     per-(token, expert) keep decisions match, and the whole forward is
     exact when they match everywhere, in particular when no row overflows
@@ -221,11 +254,18 @@ def chunk_carry_spec(cfg: ModelConfig) -> ChunkCarrySpec:
         return ChunkCarrySpec(
             "encdec", constant_size=False, exact=True, chunk_multiple=1,
             note="cross-K/V once at chunk 0, decoder ring rows after")
+    capacity_moe = cfg.family == "moe" and cfg.capacity_factor is not None
     if cfg.attn_type == "mla":
+        if capacity_moe:
+            return ChunkCarrySpec(
+                "latent", constant_size=False, exact=False,
+                chunk_multiple=1,
+                note="latent rows; chunk-local expert capacity")
         return ChunkCarrySpec(
             "latent", constant_size=False, exact=True, chunk_multiple=1,
-            note="latent ckv + shared rope key rows")
-    if cfg.family == "moe":
+            note="latent ckv + shared rope key rows"
+                 + ("; dropless experts" if cfg.family == "moe" else ""))
+    if capacity_moe:
         return ChunkCarrySpec(
             "ring", constant_size=False, exact=False, chunk_multiple=1,
             note="chunk-local expert capacity — exact iff no row drops")
@@ -245,7 +285,9 @@ def serving_features(cfg: ModelConfig) -> "dict[str, bool]":
     prompt-prefix sharing (ring K/V caches only; sharing additionally
     needs position-stable slots — no SWA wrap — and byte-keyable prompts,
     which frontend embeddings are not).  ``ep_decode``: expert-parallel
-    decode dispatch over the conduit.
+    decode dispatch over the conduit (``models/moe_ep.py``), which keeps
+    the capacity bookkeeping of the routing: a dropless layer decodes
+    with dense combine over its held experts instead.
     """
     spec = chunk_carry_spec(cfg)
     paged = (cfg.family in ("dense", "vlm", "moe")
@@ -256,7 +298,7 @@ def serving_features(cfg: ModelConfig) -> "dict[str, bool]":
         "paged": paged,
         "prefix_cache": (paged and cfg.window is None
                          and not cfg.frontend),
-        "ep_decode": cfg.family == "moe",
+        "ep_decode": cfg.family == "moe" and cfg.capacity_factor is not None,
     }
 
 

@@ -90,7 +90,7 @@ def fit_axis(mesh, entry: AxisEntry, dim: int) -> AxisEntry:
 # data.  Covers GQA/MLA attention, dense/MoE MLPs and the Mamba projections.
 _COL_PARALLEL = frozenset({
     "wq", "wk", "wv", "w_up", "w_gate", "in_proj",
-    "w_dq", "w_dkv", "w_uq", "w_uk", "w_uv",
+    "w_q", "w_dq", "w_dkv", "w_uq", "w_uk", "w_uv",
 })
 # (in_dim, out_dim) with the *input* dim model-sharded (row-parallel).
 _ROW_PARALLEL = frozenset({"wo", "w_down", "out_proj"})

@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, serving_features
 from repro.core.conduit import Conduit, transports as conduit_transports
 from repro.dist import bucketing
 from repro.dist.loss import chunked_ce_loss
@@ -402,10 +402,13 @@ def _moe_runner(cfg: ModelConfig, mesh,
 
     ``policy.moe="xla"`` (or a mesh without a usable ``expert`` axis)
     returns None — the step keeps the dense GSPMD capacity einsums, same
-    numerics.  Otherwise tokens ride the bucketed exchange of
-    ``models/moe_ep.py`` over ``Conduit("expert", policy.moe)``.
+    numerics; so does a dropless MoE (``capacity_factor=None``), which the
+    capacity-bookkept exchange does not implement.  Otherwise tokens ride
+    the bucketed exchange of ``models/moe_ep.py`` over
+    ``Conduit("expert", policy.moe)``.
     """
-    if policy.moe == "xla" or cfg.family != "moe":
+    if (policy.moe == "xla" or cfg.family != "moe"
+            or cfg.capacity_factor is None):
         return None
     return moe_ep.build_moe_ep_runner(
         cfg, mesh, transport=policy.moe, chunk_bytes=policy.chunk_bytes,
@@ -629,7 +632,7 @@ def _moe_decode_runner(cfg: ModelConfig, mesh, policy: TransportPolicy,
     ``Conduit("expert").all_to_all`` (``models/moe_ep.py`` with
     ``decode=True``).  Batches the mesh cannot split keep dense-combine —
     the weight-bound small-batch fallback."""
-    if policy.moe == "xla" or cfg.family != "moe":
+    if policy.moe == "xla" or not serving_features(cfg)["ep_decode"]:
         return None
     if batch % mesh.size:
         warnings.warn(
@@ -654,9 +657,9 @@ def build_serve_step(cfg: ModelConfig, mesh, scfg: StepConfig,
     The cache is **donated** — in/out shardings match leaf-for-leaf, so
     the output cache takes the input's memory.  On the contiguous GQA
     ring the step writes each row's new K/V vector per layer in place
-    and copies no slab of the ring (``models/decode.decode_step``); the
-    paged, MLA, encdec and hybrid caches still pass through the layer
-    scan whole.
+    and copies no slab of the ring (``models/decode.decode_step``), as
+    on the MLA latent rings; the paged, encdec and hybrid caches still
+    pass through the layer scan whole.
 
     ``sample=True`` returns greedy-sampled ``(B,)`` int32 token ids instead
     of the (B, V) logits: argmax runs on device and the server fetches one

@@ -19,9 +19,13 @@ Cache design notes (these drive the decode-shape roofline memory term):
   layer, writes each row's one new K/V vector in place before reading the
   layer's slab for attention: with the cache donated, the step moves the
   ring once (the attention's read) and copies none of it.
-* MLA (minicpm3): caches the 256-d latent + 32-d shared rope key instead of
-  per-head K/V, and uses the *absorbed* formulation (W_uk folded into the
-  query, W_uv into the output) so per-token work is O(S_buf · r).
+* MLA (minicpm3, deepseek-v2-lite): caches the latent (256 / 512-d) + the
+  shared rope key (32 / 64-d) instead of per-head K/V, and uses the
+  *absorbed* formulation (W_uk folded into the query, W_uv into the
+  output) so per-token work is O(S_buf · r).  The stacked latent rings
+  ride the layer loops' carry like the GQA ring, one row written in
+  place per layer; a MoE stack's leading dense layers run first, with the
+  running layer index.
 * SSD: O(1) state — (H, N, P) fp32 per layer + a (conv−1)-deep conv ring.
 * hybrid: SSM states for all 81 layers + one K/V cache per *application*
   of the shared attention block (weights are shared; caches are not).
@@ -258,17 +262,22 @@ def _row_update(buf, new, slot):
 
 
 def _ring_write(ring, new, layer, slot):
-    """Write each row's new vector ``new`` (B, Hkv, hd) into the stacked
-    ring (L, B, Hkv, S_buf, hd) at ``[layer, b, :, slot[b], :]``, in place.
+    """Write each row's new vector ``new`` (B, ..., d) into the stacked
+    ring (L, B, ..., S_buf, d) at ``[layer, b, ..., slot[b], :]``, in
+    place: the GQA ring (L, B, Hkv, S_buf, hd) takes (B, Hkv, hd), the
+    latent rings (L, B, S_buf, r) take (B, r).
 
     One dynamic-update-slice per row: a scatter would make the TPU
     compiler lay the whole ring out again with (Hkv, hd) minor (padded
     3.2x, copied in and out of the loop), while an update slice writes
     into the S-minor layout the ring keeps."""
     new = new.astype(ring.dtype)
+    seq_axis = ring.ndim - 2
+    mid = (0,) * (ring.ndim - 4)
     for r in range(new.shape[0]):
         ring = lax.dynamic_update_slice(
-            ring, new[r][None, None, :, None, :], (layer, r, 0, slot[r], 0))
+            ring, jnp.expand_dims(new[r], (0, 1, seq_axis)),
+            (layer, r) + mid + (slot[r], 0))
     return ring
 
 
@@ -355,48 +364,57 @@ def cross_attention_decode(cfg, p, x, kc, vc, n_valid: int):
     return (out @ p["wo"].astype(cd)).astype(x.dtype)
 
 
-def mla_decode(cfg: ModelConfig, p: Params, x: jnp.ndarray,
-               ckv: jnp.ndarray, krope: jnp.ndarray,
-               slot_pos_new: jnp.ndarray, pos: jnp.ndarray):
-    """Absorbed MLA decode.  x: (B, D); ckv: (B, S_buf, r);
-    krope: (B, S_buf, dr); ``pos`` (B,) per-row."""
-    b, _ = x.shape
-    h, dn, dr, dv = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+def _mla_decode_q(cfg: ModelConfig, p: Params, x: jnp.ndarray,
+                  pos: jnp.ndarray):
+    """The new token's absorbed MLA query and latent cache row.  x: (B, D);
+    ``pos`` (B,) per row.  Returns ``q_eff`` (B, H, r) with W_uk folded
+    in, ``q_rope`` (B, H, dr), and the rows ``c_new`` (B, r) and
+    ``kr_new`` (B, dr) of the latent and rope-key rings."""
+    h, dn = cfg.n_heads, cfg.qk_nope_dim
     r = cfg.kv_lora_rank
-    sb = ckv.shape[1]
     cd = _cd(cfg)
     xc = x.astype(cd)
-
-    q_lat = L.rms_norm(p["q_norm"], xc @ p["w_dq"].astype(cd), cfg.norm_eps)
-    q = (q_lat @ p["w_uq"].astype(cd)).reshape(b, h, dn + dr)
+    q = L.mla_query(cfg, p, xc)                           # (B, H, dn+dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = L.apply_rope(q_rope[:, :, None, :], pos[:, None, None],
-                          cfg.rope_theta)[:, :, 0]
-    w_uk = p["w_uk"].astype(cd).reshape(r, h, dn)
-    q_eff = jnp.einsum("bhd,rhd->bhr", q_nope, w_uk)      # absorb W_uk
-
+    q_rope = L.mla_rope(cfg, q_rope[:, :, None, :], pos[:, None, None])[:, :, 0]
+    with jax.named_scope("mla.absorb"):
+        w_uk = p["w_uk"].astype(cd).reshape(r, h, dn)
+        q_eff = jnp.einsum("bhd,rhd->bhr", q_nope, w_uk)  # absorb W_uk
     dkv = xc @ p["w_dkv"].astype(cd)
     c_new = L.rms_norm(p["kv_norm"], dkv[:, :r], cfg.norm_eps)
-    kr_new = L.apply_rope(dkv[:, None, None, r:], pos[:, None, None],
-                          cfg.rope_theta)[:, 0, 0]
-    slot = pos % sb
-    ckv = _row_update(ckv, c_new[:, None, :], slot)
-    krope = _row_update(krope, kr_new[:, None, :], slot)
-    scale = (dn + dr) ** -0.5
-    scores = (jnp.einsum("bhr,bsr->bhs", q_eff.astype(jnp.float32),
-                         ckv.astype(jnp.float32))
-              + jnp.einsum("bhd,bsd->bhs", q_rope.astype(jnp.float32),
-                           krope.astype(jnp.float32))) * scale
-    valid = _valid_slots(slot_pos_new, pos, None)
-    scores = jnp.where(valid[:, None, :], scores, -1e30)
-    m = scores.max(-1, keepdims=True)
-    pr = jnp.where(scores <= -1e29, 0.0, jnp.exp(scores - m))
-    pr = pr / jnp.maximum(pr.sum(-1, keepdims=True), 1e-30)
-    out_lat = jnp.einsum("bhs,bsr->bhr", pr, ckv.astype(jnp.float32))
-    w_uv = p["w_uv"].astype(cd).reshape(r, h, dv)
-    out = jnp.einsum("bhr,rhd->bhd", out_lat.astype(cd), w_uv)  # absorb W_uv
+    kr_new = L.mla_rope(cfg, dkv[:, None, None, r:],
+                        pos[:, None, None])[:, 0, 0]
+    return q_eff, q_rope, c_new, kr_new
+
+
+def _mla_decode_attend(cfg: ModelConfig, p: Params, q_eff: jnp.ndarray,
+                       q_rope: jnp.ndarray, ckv: jnp.ndarray,
+                       krope: jnp.ndarray, slot_pos_new: jnp.ndarray,
+                       pos: jnp.ndarray):
+    """Absorbed MLA attention of the new token over one layer's rings
+    ``ckv`` (B, S_buf, r) / ``krope`` (B, S_buf, dr), which already hold
+    its row; returns the output projection (B, D) in the compute dtype."""
+    b = q_eff.shape[0]
+    h, dv = cfg.n_heads, cfg.v_head_dim
+    r = cfg.kv_lora_rank
+    cd = _cd(cfg)
+    with jax.named_scope("mla.attend"):
+        scores = (jnp.einsum("bhr,bsr->bhs", q_eff.astype(jnp.float32),
+                             ckv.astype(jnp.float32))
+                  + jnp.einsum("bhd,bsd->bhs", q_rope.astype(jnp.float32),
+                               krope.astype(jnp.float32))
+                  ) * L.mla_softmax_scale(cfg)
+        valid = _valid_slots(slot_pos_new, pos, None)
+        scores = jnp.where(valid[:, None, :], scores, -1e30)
+        m = scores.max(-1, keepdims=True)
+        pr = jnp.where(scores <= -1e29, 0.0, jnp.exp(scores - m))
+        pr = pr / jnp.maximum(pr.sum(-1, keepdims=True), 1e-30)
+        out_lat = jnp.einsum("bhs,bsr->bhr", pr, ckv.astype(jnp.float32))
+    with jax.named_scope("mla.absorb"):
+        w_uv = p["w_uv"].astype(cd).reshape(r, h, dv)
+        out = jnp.einsum("bhr,rhd->bhd", out_lat.astype(cd), w_uv)  # W_uv
     out = out.reshape(b, h * dv)
-    return (out @ p["wo"].astype(cd)).astype(x.dtype), ckv, krope
+    return out @ p["wo"].astype(cd)
 
 
 def mamba2_decode(cfg: ModelConfig, p: Params, x: jnp.ndarray,
@@ -463,18 +481,23 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Cache,
     else:
         slot_pos_new = None
 
-    if cfg.family in ("dense", "vlm", "moe") and cfg.attn_type != "mla":
-        def ffn(normed2, lp):
-            if cfg.family == "moe":
-                if moe_runner is not None:
-                    return moe_runner(cfg, lp["moe"], normed2[:, None, :])[:, 0]
-                return L.moe(cfg, lp["moe"], normed2[:, None, :],
-                             dense_combine=True)[:, 0]
-            return L.mlp(cfg, lp["mlp"], normed2)
+    def ffn(normed2, lp):
+        """The layer's feed-forward: the MLP, or the MoE of a MoE layer."""
+        if "moe" in lp:
+            if moe_runner is not None:
+                return moe_runner(cfg, lp["moe"], normed2[:, None, :])[:, 0]
+            return L.moe(cfg, lp["moe"], normed2[:, None, :],
+                         dense_combine=True)[:, 0]
+        return L.mlp(cfg, lp["mlp"], normed2)
 
+    # the layer stacks in order: a MoE stack's leading dense layers first
+    stacks = [params[k] for k in ("dense_layers", "layers") if k in params]
+
+    if cfg.family in ("dense", "vlm", "moe") and cfg.attn_type != "mla":
         if "kp" in cache:
             # paged: gather the block-table view, run the *identical*
             # contiguous attention, scatter only the new row back
+            assert len(stacks) == 1, "the paged pool holds one layer stack"
             bids = cache["block_ids"]
             sb = cache["slot_pos"].shape[1]
             slot = pos % sb
@@ -517,24 +540,37 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Cache,
                 f = ffn(L.apply_norm(cfg, lp["ln2"], h), lp)
                 return (h + f, ks, vs, l + 1), None
 
-            (x, ks, vs, _), _ = lax.scan(
-                body, (x, cache["k"], cache["v"], jnp.int32(0)),
-                params["layers"])
+            carry = (x, cache["k"], cache["v"], jnp.int32(0))
+            for stack in stacks:
+                carry, _ = lax.scan(body, carry, stack)
+            x, ks, vs, _ = carry
             cache = dict(cache, k=ks, v=vs, slot_pos=slot_pos_new,
                          pos=pos + 1)
 
     elif cfg.attn_type == "mla":
-        def body(h, layer):
-            lp, ck, kr = layer
-            normed = L.apply_norm(cfg, lp["ln1"], h)
-            a, ck, kr = mla_decode(cfg, lp["attn"], normed, ck, kr,
-                                   slot_pos_new, pos)
-            h = h + a
-            f = L.mlp(cfg, lp["mlp"], L.apply_norm(cfg, lp["ln2"], h))
-            return h + f, (ck, kr)
+        # the stacked latent rings (L, B, S_buf, r) and (L, B, S_buf, dr)
+        # ride the layer loops' carry with the running layer index, as the
+        # GQA ring does: each layer writes its rows in place, then attends
+        # over its slab
+        slot = pos % cache["ckv"].shape[2]
 
-        x, (cks, krs) = lax.scan(
-            body, x, (params["layers"], cache["ckv"], cache["krope"]))
+        def body(carry, lp):
+            h, cks, krs, l = carry
+            normed = L.apply_norm(cfg, lp["ln1"], h)
+            q_eff, q_rope, c_new, kr_new = _mla_decode_q(cfg, lp["attn"],
+                                                         normed, pos)
+            cks = _ring_write(cks, c_new, l, slot)
+            krs = _ring_write(krs, kr_new, l, slot)
+            a = _mla_decode_attend(cfg, lp["attn"], q_eff, q_rope, cks[l],
+                                   krs[l], slot_pos_new, pos)
+            h = h + a.astype(normed.dtype)
+            f = ffn(L.apply_norm(cfg, lp["ln2"], h), lp)
+            return (h + f, cks, krs, l + 1), None
+
+        carry = (x, cache["ckv"], cache["krope"], jnp.int32(0))
+        for stack in stacks:
+            carry, _ = lax.scan(body, carry, stack)
+        x, cks, krs, _ = carry
         cache = dict(cache, ckv=cks, krope=krs, slot_pos=slot_pos_new,
                      pos=pos + 1)
 

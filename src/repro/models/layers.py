@@ -99,16 +99,62 @@ def apply_norm(cfg: ModelConfig, params: Params, x: jnp.ndarray) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def rope_freqs(dim: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor, ``0.1·m·ln(factor) + 1``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., S, D) with D even; positions: (S,) or broadcastable."""
+def yarn_range(dim: int, theta: float, scaling) -> tuple:
+    """YaRN's (low, high) frequency indices: the dims that turn ``beta``
+    times over the original context, ``dim·ln(L/(2π·beta)) / (2 ln
+    theta)``, floored at ``beta_fast`` and ceiled at ``beta_slow``."""
+    ys = dict(scaling)
+    n_ctx = ys["original_max_position_embeddings"]
+
+    def dim_at(beta):
+        return dim * math.log(n_ctx / (beta * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(dim_at(ys["beta_fast"])), 0)
+    high = min(math.ceil(dim_at(ys["beta_slow"])), dim - 1)
+    return low, high
+
+
+def rope_freqs(dim: int, theta: float, scaling=None) -> jnp.ndarray:
+    """Inverse frequencies of the (dim/2) rotated pairs; with a YaRN
+    ``scaling``, a linear ramp from the plain frequencies (below ``low``)
+    to the plain ones over ``factor`` (above ``high``)."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if scaling is None:
+        return freqs
+    low, high = yarn_range(dim, theta, scaling)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return freqs / dict(scaling)["factor"] * ramp + freqs * (1.0 - ramp)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               scaling=None, interleave: bool = False) -> jnp.ndarray:
+    """x: (..., S, D) with D even; positions: (S,) or broadcastable.
+
+    ``interleave`` rotates the pairs (2i, 2i+1) (the input is first
+    de-interleaved into evens then odds, and the result keeps that
+    layout); ``scaling`` is a YaRN ``rope_scaling`` (``rope_freqs``), whose
+    cos and sin carry ``mscale(mscale) / mscale(mscale_all_dim)``."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # (D/2,)
+    if interleave:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    freqs = rope_freqs(d, theta, scaling)              # (D/2,)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (S, D/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaling is not None:
+        ys = dict(scaling)
+        m = (yarn_mscale(ys["factor"], ys.get("mscale", 1.0))
+             / yarn_mscale(ys["factor"], ys.get("mscale_all_dim", 0.0)))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = x[..., : d // 2], x[..., d // 2:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate(
@@ -350,20 +396,58 @@ def cross_kv(cfg: ModelConfig, params: Params, enc_out: jnp.ndarray):
 
 
 def init_mla(cfg: ModelConfig, key) -> Params:
+    """MLA weights; ``q_lora_rank`` 0 takes the query straight from the
+    input (``w_q``) instead of through a normed low-rank ``w_dq``/``w_uq``."""
     dt = _dtype(cfg)
     ks = jax.random.split(key, 8)
     h, dn, dr, dv = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     depth_scale = 0.02 / math.sqrt(2 * max(cfg.n_layers, 1))
+    if cfg.q_lora_rank:
+        q = {"w_dq": _init(ks[0], (cfg.d_model, cfg.q_lora_rank), dt),
+             "q_norm": {"scale": jnp.ones((cfg.q_lora_rank,), dt)},
+             "w_uq": _init(ks[1], (cfg.q_lora_rank, h * (dn + dr)), dt)}
+    else:
+        q = {"w_q": _init(ks[0], (cfg.d_model, h * (dn + dr)), dt)}
     return {
-        "w_dq": _init(ks[0], (cfg.d_model, cfg.q_lora_rank), dt),
-        "q_norm": {"scale": jnp.ones((cfg.q_lora_rank,), dt)},
-        "w_uq": _init(ks[1], (cfg.q_lora_rank, h * (dn + dr)), dt),
+        **q,
         "w_dkv": _init(ks[2], (cfg.d_model, cfg.kv_lora_rank + dr), dt),
         "kv_norm": {"scale": jnp.ones((cfg.kv_lora_rank,), dt)},
         "w_uk": _init(ks[3], (cfg.kv_lora_rank, h * dn), dt),
         "w_uv": _init(ks[4], (cfg.kv_lora_rank, h * dv), dt),
         "wo": _init(ks[5], (h * dv, cfg.d_model), dt, depth_scale),
     }
+
+
+def mla_query(cfg: ModelConfig, params: Params,
+              xc: jnp.ndarray) -> jnp.ndarray:
+    """(..., D) compute-dtype input -> (..., H, dn + dr) queries."""
+    cd = _cdtype(cfg)
+    if cfg.q_lora_rank:
+        q_lat = rms_norm(params["q_norm"], xc @ params["w_dq"].astype(cd),
+                         cfg.norm_eps)
+        q = q_lat @ params["w_uq"].astype(cd)
+    else:
+        q = xc @ params["w_q"].astype(cd)
+    return q.reshape(xc.shape[:-1] + (cfg.n_heads,
+                                      cfg.qk_nope_dim + cfg.qk_rope_dim))
+
+
+def mla_rope(cfg: ModelConfig, x: jnp.ndarray,
+             positions: jnp.ndarray) -> jnp.ndarray:
+    """The rotary embedding of MLA's rope part, with the config's YaRN
+    scaling and pair layout."""
+    return apply_rope(x, positions, cfg.rope_theta, cfg.rope_scaling,
+                      cfg.rope_interleave)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``(dn + dr)^-1/2``, times ``mscale(mscale_all_dim)^2`` under YaRN."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    if cfg.rope_scaling is not None:
+        ys = dict(cfg.rope_scaling)
+        if ys.get("mscale_all_dim"):
+            scale *= yarn_mscale(ys["factor"], ys["mscale_all_dim"]) ** 2
+    return scale
 
 
 def mla_attention(
@@ -379,18 +463,16 @@ def mla_attention(
     cd = _cdtype(cfg)
     xc = x.astype(cd)
 
-    q_lat = rms_norm(params["q_norm"], xc @ params["w_dq"].astype(cd), cfg.norm_eps)
-    q = (q_lat @ params["w_uq"].astype(cd)).reshape(b, s, h, dn + dr)
+    q = mla_query(cfg, params, xc)                        # (B, S, H, dn+dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope.transpose(0, 2, 1, 3), positions, cfg.rope_theta)
+    q_rope = mla_rope(cfg, q_rope.transpose(0, 2, 1, 3), positions)
 
     dkv = xc @ params["w_dkv"].astype(cd)                 # (B, S, r + dr)
     c_kv = rms_norm(params["kv_norm"], dkv[..., :r], cfg.norm_eps)
-    k_rope = apply_rope(
-        dkv[..., r:][:, None], positions, cfg.rope_theta
-    )                                                     # (B, 1, S, dr) shared
-    k_nope = (c_kv @ params["w_uk"].astype(cd)).reshape(b, s, h, dn)
-    vfull = (c_kv @ params["w_uv"].astype(cd)).reshape(b, s, h, dv)
+    k_rope = mla_rope(cfg, dkv[..., r:][:, None], positions)  # (B,1,S,dr)
+    with jax.named_scope("mla.absorb"):
+        k_nope = (c_kv @ params["w_uk"].astype(cd)).reshape(b, s, h, dn)
+        vfull = (c_kv @ params["w_uv"].astype(cd)).reshape(b, s, h, dv)
 
     qh = jnp.concatenate(
         [q_nope.transpose(0, 2, 1, 3), q_rope], axis=-1
@@ -400,8 +482,9 @@ def mla_attention(
          jnp.broadcast_to(k_rope, (b, h, s, dr))], axis=-1
     )
     vh = vfull.transpose(0, 2, 1, 3)
-    out = attention_core(cfg, qh, kh, vh, causal=True,
-                         scale=(dn + dr) ** -0.5)
+    with jax.named_scope("mla.attend"):
+        out = attention_core(cfg, qh, kh, vh, causal=True,
+                             scale=mla_softmax_scale(cfg))
     out = out.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
     y = (out @ params["wo"].astype(cd)).astype(x.dtype)
     if return_cache:
@@ -456,12 +539,14 @@ def mlp(cfg: ModelConfig, params: Params, x: jnp.ndarray) -> jnp.ndarray:
 
 
 def init_moe(cfg: ModelConfig, key) -> Params:
+    """The router over all ``n_experts`` and the weights of the
+    ``n_local_experts`` held here, plus the shared experts as one MLP."""
     dt = _dtype(cfg)
     ks = jax.random.split(key, 5)
-    e, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff
+    e, d, f = cfg.n_local_experts, cfg.d_model, cfg.d_ff
     depth_scale = 0.02 / math.sqrt(2 * max(cfg.n_layers, 1))
     p = {
-        "router": _init(ks[0], (d, e), jnp.float32),
+        "router": _init(ks[0], (d, cfg.n_experts), jnp.float32),
         "w_up": _init(ks[1], (e, d, f), dt),
         "w_down": _init(ks[2], (e, f, d), dt, depth_scale),
     }
@@ -484,6 +569,18 @@ def _expert_ffn(cfg: ModelConfig, params: Params, xe: jnp.ndarray) -> jnp.ndarra
     return jnp.einsum("...ecf,efd->...ecd", up, params["w_down"].astype(cd))
 
 
+def moe_topk(cfg: ModelConfig, router: jnp.ndarray, xc: jnp.ndarray):
+    """Softmax over all ``n_experts`` router outputs (float32) and the
+    greedy top-k: ``(weights (B,S,K), idx (B,S,K))``.  The weights are
+    renormalised to sum to 1 only under ``norm_topk_prob``."""
+    logits = jnp.einsum("bsd,de->bse", xc.astype(jnp.float32), router)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, idx = lax.top_k(probs, cfg.experts_per_token)   # (B, S, K)
+    if cfg.norm_topk_prob:
+        weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+    return weights, idx
+
+
 def moe_route(cfg: ModelConfig, router: jnp.ndarray, xc: jnp.ndarray):
     """Top-k routing + per-row capacity bookkeeping.
 
@@ -492,15 +589,12 @@ def moe_route(cfg: ModelConfig, router: jnp.ndarray, xc: jnp.ndarray):
     (``models/moe_ep.py``) call it, which is what makes the two paths
     token-for-token equivalent (same slots, same drops).
 
-    Returns ``(weights (B,S,K) normalized, idx (B,S,K), keep (B,S,K) bool,
+    Returns ``(weights (B,S,K), idx (B,S,K), keep (B,S,K) bool,
     dst (B,S,K) flat slot with ``e*cap`` as the overflow bin, cap)``.
     """
     b, s, _ = xc.shape
     e, k = cfg.n_experts, cfg.experts_per_token
-    logits = jnp.einsum("bsd,de->bse", xc.astype(jnp.float32), router)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, idx = lax.top_k(probs, k)                   # (B, S, K)
-    weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+    weights, idx = moe_topk(cfg, router, xc)
     cap = max(1, int(s * k / e * cfg.capacity_factor))
     onehot = jax.nn.one_hot(idx, e, dtype=jnp.int32)      # (B, S, K, E)
     flat_choice = onehot.reshape(b, s * k, e)
@@ -554,10 +648,16 @@ def moe(cfg: ModelConfig, params: Params, x: jnp.ndarray,
     per-device capacity with row-aligned sharding).  Dropped tokens (over
     capacity) fall through on the residual path, as in standard top-k MoE.
 
-    ``dense_combine=True`` computes every expert on every token and mixes by
-    router weights — used for decode, where S is 1 and the layer is bound by
-    reading the expert *weights* anyway, so the extra FLOPs are free and the
-    gather/scatter (and its collectives) disappear.
+    ``dense_combine=True`` computes every held expert on every token and
+    mixes by router weights — used for decode, where S is 1 and the layer
+    is bound by reading the expert *weights* anyway, so the extra FLOPs
+    are free and the gather/scatter (and its collectives) disappear.
+
+    ``capacity_factor=None`` routes dropless: every (token, held expert)
+    pair is computed, by the grouped matmul of :func:`moe_dropless`.
+    Routing is over all ``n_experts`` router outputs; the layer computes
+    the part of the result that its held experts
+    ``[expert_offset, expert_offset + n_local_experts)`` give.
     """
     b, s, d = x.shape
     e = cfg.n_experts
@@ -565,15 +665,25 @@ def moe(cfg: ModelConfig, params: Params, x: jnp.ndarray,
     xc = x.astype(cd)
 
     if dense_combine:
-        # routing still owned by moe_route; the capacity bookkeeping it
-        # also returns is unused here and DCE'd under jit
-        weights, idx, _, _, _ = moe_route(cfg, params["router"], xc)
-        combine = jnp.zeros((b, s, e), jnp.float32).at[
-            jnp.arange(b)[:, None, None], jnp.arange(s)[None, :, None], idx
-        ].add(weights)
-        dense = _expert_ffn(cfg, params, jnp.broadcast_to(xc[:, None], (b, e, s, d)))
-        y = jnp.einsum("besd,bse->bsd", dense, combine.astype(cd))
+        with jax.named_scope("moe.route"):
+            weights, idx = moe_topk(cfg, params["router"], xc)
+            combine = jnp.zeros((b, s, e), jnp.float32).at[
+                jnp.arange(b)[:, None, None], jnp.arange(s)[None, :, None],
+                idx].add(weights)
+            held = lax.slice_in_dim(combine, cfg.expert_offset,
+                                    cfg.expert_offset + cfg.n_local_experts,
+                                    axis=2)
+        with jax.named_scope("moe.experts"):
+            dense = _expert_ffn(cfg, params, jnp.broadcast_to(
+                xc[:, None], (b, cfg.n_local_experts, s, d)))
+        with jax.named_scope("moe.combine"):
+            y = jnp.einsum("besd,bse->bsd", dense, held.astype(cd))
+    elif cfg.capacity_factor is None:
+        with jax.named_scope("moe.route"):
+            weights, idx = moe_topk(cfg, params["router"], xc)
+        y = moe_dropless(cfg, params, xc, weights, idx)
     else:
+        assert cfg.n_local_experts == e, "capacity routing holds every expert"
         weights, _, keep, dst, cap = moe_route(cfg, params["router"], xc)
         xe = moe_dispatch(xc, dst, keep, e, cap)
         ye = _expert_ffn(cfg, params, xe)
@@ -582,6 +692,72 @@ def moe(cfg: ModelConfig, params: Params, x: jnp.ndarray,
     if cfg.n_shared_experts:
         y = y + mlp(cfg, params["shared"], xc)
     return y.astype(x.dtype)
+
+
+def _gmm(cfg: ModelConfig, lhs: jnp.ndarray, rhs: jnp.ndarray,
+         sizes: jnp.ndarray) -> jnp.ndarray:
+    """The grouped matmul of the resolved kernel path: the Pallas
+    ``moe_gmm`` under ``pallas``, ``lax.ragged_dot`` otherwise."""
+    from repro.kernels.grouped_matmul import gmm_ref, moe_gmm
+
+    if resolve_attn_impl(cfg) == "pallas":
+        return moe_gmm(lhs, rhs, sizes)
+    return gmm_ref(lhs, rhs, sizes)
+
+
+def moe_dropless(cfg: ModelConfig, params: Params, xc: jnp.ndarray,
+                 weights: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """The held experts' part of a dropless MoE layer, float32-combined.
+
+    The (token, expert) pairs routed to a held expert are sorted by
+    expert into row groups, each group padded to a multiple of the
+    kernel's ``TILE_M`` rows; the gate, up and down products run as three
+    grouped matmuls over them, and each token gathers back its pairs'
+    rows and mixes them by router weight.  Pairs routed to experts held
+    elsewhere contribute nothing.  Shapes are static: the row extent is
+    the worst case, ``T·min(K, H)`` rows plus a tile of padding a group,
+    and the kernel computes only the tiles that hold rows."""
+    from repro.kernels.grouped_matmul import TILE_M
+
+    b, s, d = xc.shape
+    t, k = b * s, idx.shape[-1]
+    h = cfg.n_local_experts
+    tm = TILE_M
+    m_cap = -(-(t * min(k, h) + h * (tm - 1)) // tm) * tm
+    with jax.named_scope("moe.route"):
+        local = idx.reshape(t * k) - cfg.expert_offset
+        held = (local >= 0) & (local < h)
+        group = jnp.where(held, local, h)           # h: held elsewhere
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.zeros((h + 1,), jnp.int32).at[group].add(1)
+        start = jnp.cumsum(sizes) - sizes           # unpadded group starts
+        padded = (sizes[:h] + tm - 1) // tm * tm
+        pstart = jnp.concatenate([jnp.cumsum(padded) - padded,
+                                  jnp.full((1,), m_cap, jnp.int32)])
+        g_sorted = group[order]
+        row_sorted = jnp.where(
+            g_sorted < h,
+            pstart[g_sorted] + jnp.arange(t * k) - start[g_sorted], m_cap)
+        row = jnp.zeros((t * k,), jnp.int32).at[order].set(row_sorted)
+        token_of_row = jnp.full((m_cap + 1,), t, jnp.int32).at[row].set(
+            jnp.arange(t * k, dtype=jnp.int32) // k)[:m_cap]
+        x_rows = jnp.concatenate([xc.reshape(t, d),
+                                  jnp.zeros((1, d), xc.dtype)])[token_of_row]
+    with jax.named_scope("moe.experts"):
+        cd = _cdtype(cfg)
+        up = _gmm(cfg, x_rows, params["w_up"].astype(cd), padded)
+        if cfg.gated_mlp:
+            up = _act(cfg.activation,
+                      _gmm(cfg, x_rows, params["w_gate"].astype(cd),
+                           padded)) * up
+        else:
+            up = _act(cfg.activation, up)
+        out = _gmm(cfg, up, params["w_down"].astype(cd), padded)
+    with jax.named_scope("moe.combine"):
+        got = out[jnp.minimum(row, m_cap - 1)].reshape(t, k, d)
+        got = jnp.where(held.reshape(t, k, 1), got.astype(jnp.float32), 0.0)
+        y = (got * weights.reshape(t, k, 1)).sum(axis=1)
+    return y.reshape(b, s, d)
 
 
 def moe_aux_loss(cfg: ModelConfig, x: jnp.ndarray, params: Params) -> jnp.ndarray:

@@ -49,15 +49,19 @@ def _stack_init(init_fn, cfg: ModelConfig, key, n: int) -> Params:
     return jax.vmap(lambda k: init_fn(cfg, k))(keys)
 
 
-def _init_dense_layer(cfg: ModelConfig, key) -> Params:
+def _init_attn(cfg: ModelConfig, key) -> Params:
+    return (L.init_mla(cfg, key) if cfg.attn_type == "mla"
+            else L.init_attention(cfg, key))
+
+
+def _init_dense_layer(cfg: ModelConfig, key,
+                      d_ff: Optional[int] = None) -> Params:
     k1, k2, k3, k4 = jax.random.split(key, 4)
-    attn = (L.init_mla(cfg, k1) if cfg.attn_type == "mla"
-            else L.init_attention(cfg, k1))
     return {
         "ln1": L.init_norm(cfg, k3),
-        "attn": attn,
+        "attn": _init_attn(cfg, k1),
         "ln2": L.init_norm(cfg, k4),
-        "mlp": L.init_mlp(cfg, k2),
+        "mlp": L.init_mlp(cfg, k2, d_ff=d_ff),
     }
 
 
@@ -65,7 +69,7 @@ def _init_moe_layer(cfg: ModelConfig, key) -> Params:
     k1, k2, k3, k4 = jax.random.split(key, 4)
     return {
         "ln1": L.init_norm(cfg, k3),
-        "attn": L.init_attention(cfg, k1),
+        "attn": _init_attn(cfg, k1),
         "ln2": L.init_norm(cfg, k4),
         "moe": L.init_moe(cfg, k2),
     }
@@ -103,7 +107,14 @@ def init_params(cfg: ModelConfig, key) -> Params:
     if cfg.family in ("dense", "vlm"):
         p["layers"] = _stack_init(_init_dense_layer, cfg, ks[3], cfg.n_layers)
     elif cfg.family == "moe":
-        p["layers"] = _stack_init(_init_moe_layer, cfg, ks[3], cfg.n_layers)
+        # the leading dense layers and the MoE layers are two stacks, run
+        # one after the other with a running layer index
+        if cfg.first_dense_layers:
+            p["dense_layers"] = _stack_init(
+                lambda c, k: _init_dense_layer(c, k, c.dense_d_ff or None),
+                cfg, ks[5], cfg.first_dense_layers)
+        p["layers"] = _stack_init(_init_moe_layer, cfg, ks[3],
+                                  cfg.n_moe_layers)
     elif cfg.family == "ssm":
         p["layers"] = _stack_init(_init_ssm_layer, cfg, ks[3], cfg.n_layers)
     elif cfg.family == "hybrid":
@@ -148,8 +159,9 @@ def _dense_block(cfg, p, x, positions):
 
 
 def _moe_block(cfg, p, x, positions):
+    attn_fn = L.mla_attention if cfg.attn_type == "mla" else L.attention
     a_in = constrain(L.apply_norm(cfg, p["ln1"], x), "block_input")
-    h = x + L.attention(cfg, p["attn"], a_in, positions)
+    h = x + attn_fn(cfg, p["attn"], a_in, positions)
     normed = constrain(L.apply_norm(cfg, p["ln2"], h), "block_input")
     ep = moe_ffn_runner()
     if ep is not None:
@@ -204,6 +216,13 @@ def forward_hidden(
             return h, None
         x, _ = lax.scan(_maybe_remat(cfg, body), x, params["layers"])
     elif cfg.family == "moe":
+        if "dense_layers" in params:
+            def lead(h, lp):
+                h = constrain(_dense_block(cfg, lp, h, positions), "residual")
+                return h, None
+            x, _ = lax.scan(_maybe_remat(cfg, lead), x,
+                            params["dense_layers"])
+
         def body(carry, lp):
             h, a = carry
             h, aux_l = _moe_block(cfg, lp, h, positions)
@@ -374,9 +393,10 @@ def count_params_analytic(cfg: ModelConfig, active_only: bool = False) -> int:
     def attn_params():
         if cfg.attn_type == "mla":
             h = cfg.n_heads
-            return (d * cfg.q_lora_rank + cfg.q_lora_rank
-                    + cfg.q_lora_rank * h * (cfg.qk_nope_dim + cfg.qk_rope_dim)
-                    + d * (cfg.kv_lora_rank + cfg.qk_rope_dim) + cfg.kv_lora_rank
+            q_out = h * (cfg.qk_nope_dim + cfg.qk_rope_dim)
+            q = (d * cfg.q_lora_rank + cfg.q_lora_rank
+                 + cfg.q_lora_rank * q_out) if cfg.q_lora_rank else d * q_out
+            return (q + d * (cfg.kv_lora_rank + cfg.qk_rope_dim) + cfg.kv_lora_rank
                     + cfg.kv_lora_rank * h * cfg.qk_nope_dim
                     + cfg.kv_lora_rank * h * cfg.v_head_dim
                     + h * cfg.v_head_dim * d)
@@ -388,7 +408,8 @@ def count_params_analytic(cfg: ModelConfig, active_only: bool = False) -> int:
         return (3 if cfg.gated_mlp else 2) * d * ff
 
     def moe_params():
-        n_e = (cfg.experts_per_token if active_only else cfg.n_experts)
+        n_e = (cfg.experts_per_token if active_only
+               else cfg.n_local_experts)
         total = d * cfg.n_experts  # router (always resident)
         total += n_e * (3 if cfg.gated_mlp else 2) * d * f
         if cfg.n_shared_experts:
@@ -411,7 +432,9 @@ def count_params_analytic(cfg: ModelConfig, active_only: bool = False) -> int:
     if cfg.family in ("dense", "vlm"):
         total += cfg.n_layers * (attn_params() + mlp_params() + 2 * d)
     elif cfg.family == "moe":
-        total += cfg.n_layers * (attn_params() + moe_params() + 2 * d)
+        total += cfg.first_dense_layers * (
+            attn_params() + mlp_params(cfg.dense_d_ff) + 2 * d)
+        total += cfg.n_moe_layers * (attn_params() + moe_params() + 2 * d)
     elif cfg.family == "ssm":
         total += cfg.n_layers * ssm_params()
     elif cfg.family == "hybrid":

@@ -112,6 +112,34 @@ def _ring_fill(seq_t: jnp.ndarray, sb: int, seq_axis: int):
 # ---------------------------------------------------------------------------
 
 
+def _ffn(cfg: ModelConfig, lp: Params, x: jnp.ndarray) -> jnp.ndarray:
+    """The layer's feed-forward: the MLP, or the MoE of a MoE layer."""
+    if "moe" in lp:
+        return L.moe(cfg, lp["moe"], x)
+    return L.mlp(cfg, lp["mlp"], x)
+
+
+def _scan_layers(cfg: ModelConfig, body, carry, params: Params, xs=()):
+    """``lax.scan`` of ``body`` over the layer stacks in order: a MoE
+    stack's leading dense layers (``params["dense_layers"]``), then
+    ``params["layers"]``.  ``xs`` are per-layer inputs stacked over all
+    layers, split to match; each layer gets ``(lp, *xs_l)`` (or ``lp``
+    alone without ``xs``), and the stacked outputs are joined."""
+    stacks = [params[k] for k in ("dense_layers", "layers") if k in params]
+    outs, lo = [], 0
+    for stack in stacks:
+        n = jax.tree.leaves(stack)[0].shape[0]
+        sl = (tuple(jax.tree.map(lambda a: a[lo:lo + n], x) for x in xs)
+              if len(stacks) > 1 else tuple(xs))
+        carry, ys = lax.scan(_maybe_remat(cfg, body), carry,
+                             (stack,) + sl if xs else stack)
+        outs.append(ys)
+        lo += n
+    if len(outs) == 1:
+        return carry, outs[0]
+    return carry, jax.tree.map(lambda *a: jnp.concatenate(a), *outs)
+
+
 def _prefill_gqa(cfg: ModelConfig, params: Params, x: jnp.ndarray,
                  positions: jnp.ndarray, sb: int):
     def body(h, lp):
@@ -119,17 +147,13 @@ def _prefill_gqa(cfg: ModelConfig, params: Params, x: jnp.ndarray,
         a, (k, v) = L.attention(cfg, lp["attn"], normed, positions,
                                 return_kv=True)
         h = h + a
-        normed2 = L.apply_norm(cfg, lp["ln2"], h)
-        if cfg.family == "moe":
-            h = h + L.moe(cfg, lp["moe"], normed2)
-        else:
-            h = h + L.mlp(cfg, lp["mlp"], normed2)
+        h = h + _ffn(cfg, lp, L.apply_norm(cfg, lp["ln2"], h))
         kc, _ = _ring_fill(k, sb, seq_axis=2)
         vc, _ = _ring_fill(v, sb, seq_axis=2)
         return constrain(h, "residual"), (kc.astype(jnp.dtype(cfg.param_dtype)),
                                           vc.astype(jnp.dtype(cfg.param_dtype)))
 
-    x, (ks, vs) = lax.scan(_maybe_remat(cfg, body), x, params["layers"])
+    x, (ks, vs) = _scan_layers(cfg, body, x, params)
     slot_pos, _ = _slot_map(x.shape[1], sb)
     return x, {"k": ks, "v": vs, "slot_pos": slot_pos}
 
@@ -143,12 +167,12 @@ def _prefill_mla(cfg: ModelConfig, params: Params, x: jnp.ndarray,
         a, (ckv, krope) = L.mla_attention(cfg, lp["attn"], normed, positions,
                                           return_cache=True)
         h = h + a
-        h = h + L.mlp(cfg, lp["mlp"], L.apply_norm(cfg, lp["ln2"], h))
+        h = h + _ffn(cfg, lp, L.apply_norm(cfg, lp["ln2"], h))
         cc, _ = _ring_fill(ckv, sb, seq_axis=1)
         kr, _ = _ring_fill(krope, sb, seq_axis=1)
         return constrain(h, "residual"), (cc.astype(dt), kr.astype(dt))
 
-    x, (cks, krs) = lax.scan(_maybe_remat(cfg, body), x, params["layers"])
+    x, (cks, krs) = _scan_layers(cfg, body, x, params)
     slot_pos, _ = _slot_map(x.shape[1], sb)
     return x, {"ckv": cks, "krope": krs, "slot_pos": slot_pos}
 
@@ -461,18 +485,13 @@ def _chunk_body(cfg: ModelConfig, params: Params, ks: jnp.ndarray,
         a, kbuf, vbuf = _chunk_attention(cfg, lp["attn"], normed,
                                          kbuf, vbuf, lo)
         h = h + a
-        normed2 = L.apply_norm(cfg, lp["ln2"], h)
-        if cfg.family == "moe":
-            # chunk-local capacity: moe_route sees this chunk's rows only,
-            # so its capacity bookkeeping is per chunk — the documented
-            # exact-iff-no-overflow bound (moe_chunk_agree_mask)
-            h = h + L.moe(cfg, lp["moe"], normed2)
-        else:
-            h = h + L.mlp(cfg, lp["mlp"], normed2)
+        # a MoE with a capacity bookkeeps it per chunk: moe_route sees
+        # this chunk's rows only — the documented exact-iff-no-overflow
+        # bound (moe_chunk_agree_mask)
+        h = h + _ffn(cfg, lp, L.apply_norm(cfg, lp["ln2"], h))
         return constrain(h, "residual"), (kbuf, vbuf)
 
-    h, (ks, vs) = lax.scan(_maybe_remat(cfg, body), x,
-                           (params["layers"], ks, vs))
+    h, (ks, vs) = _scan_layers(cfg, body, x, params, (ks, vs))
     return ks, vs, h
 
 
@@ -491,28 +510,28 @@ def _chunk_mla_attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     xc = x.astype(cd)
     positions = lo + jnp.arange(c)
 
-    q_lat = L.rms_norm(p["q_norm"], xc @ p["w_dq"].astype(cd), cfg.norm_eps)
-    q = (q_lat @ p["w_uq"].astype(cd)).reshape(b, c, h, dn + dr)
+    q = L.mla_query(cfg, p, xc)                           # (B, C, H, dn+dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = L.apply_rope(q_rope.transpose(0, 2, 1, 3), positions,
-                          cfg.rope_theta)
+    q_rope = L.mla_rope(cfg, q_rope.transpose(0, 2, 1, 3), positions)
 
     dkv = xc @ p["w_dkv"].astype(cd)
     c_kv = L.rms_norm(p["kv_norm"], dkv[..., :r], cfg.norm_eps)
-    k_rope = L.apply_rope(dkv[..., r:][:, None], positions, cfg.rope_theta)
+    k_rope = L.mla_rope(cfg, dkv[..., r:][:, None], positions)
     cbuf = lax.dynamic_update_slice_in_dim(cbuf, c_kv, lo, axis=1)
     kbuf = lax.dynamic_update_slice_in_dim(kbuf, k_rope[:, 0], lo, axis=1)
 
     s_full = cbuf.shape[1]
-    k_nope = (cbuf @ p["w_uk"].astype(cd)).reshape(b, s_full, h, dn)
-    vfull = (cbuf @ p["w_uv"].astype(cd)).reshape(b, s_full, h, dv)
+    with jax.named_scope("mla.absorb"):
+        k_nope = (cbuf @ p["w_uk"].astype(cd)).reshape(b, s_full, h, dn)
+        vfull = (cbuf @ p["w_uv"].astype(cd)).reshape(b, s_full, h, dv)
     qh = jnp.concatenate([q_nope.transpose(0, 2, 1, 3), q_rope], axis=-1)
     kh = jnp.concatenate(
         [k_nope.transpose(0, 2, 1, 3),
          jnp.broadcast_to(kbuf[:, None], (b, h, s_full, dr))], axis=-1)
     vh = vfull.transpose(0, 2, 1, 3)
-    out = L.attention_core(cfg, qh, kh, vh, causal=True,
-                           scale=(dn + dr) ** -0.5, q_offset=lo)
+    with jax.named_scope("mla.attend"):
+        out = L.attention_core(cfg, qh, kh, vh, causal=True,
+                               scale=L.mla_softmax_scale(cfg), q_offset=lo)
     out = out.transpose(0, 2, 1, 3).reshape(b, c, h * dv)
     y = (out @ p["wo"].astype(cd)).astype(x.dtype)
     return y, cbuf, kbuf
@@ -528,11 +547,10 @@ def _chunk_mla_body(cfg: ModelConfig, params: Params, cks: jnp.ndarray,
         a, cbuf, kbuf = _chunk_mla_attention(cfg, lp["attn"], normed,
                                              cbuf, kbuf, lo)
         h = h + a
-        h = h + L.mlp(cfg, lp["mlp"], L.apply_norm(cfg, lp["ln2"], h))
+        h = h + _ffn(cfg, lp, L.apply_norm(cfg, lp["ln2"], h))
         return constrain(h, "residual"), (cbuf, kbuf)
 
-    h, (cks, krs) = lax.scan(_maybe_remat(cfg, body), x,
-                             (params["layers"], cks, krs))
+    h, (cks, krs) = _scan_layers(cfg, body, x, params, (cks, krs))
     return cks, krs, h
 
 

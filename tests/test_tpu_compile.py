@@ -206,3 +206,48 @@ def test_decode_step_updates_ring_in_place_smollm(topo):
     for m in re.finditer(r"= \w+\[([\d,]*)\]\S* copy\(", compiled.as_text()):
         dims = [int(d) for d in m.group(1).split(",") if d and d != "1"]
         assert sorted(dims) not in whole, m.group(0)
+
+
+def test_decode_step_updates_latent_ring_in_place_deepseek(topo):
+    """The deepseek-v2-lite cell's decode step at 128 slots x 4096 (the
+    chip's cut: the dense layer and 6 MoE layers, 16 of 64 experts held):
+    the latent rings ride the layer loops in place, so the temporaries
+    are far below the 4.2 GB of rings and no copy holds a whole ring or
+    one layer's slab of it."""
+    import re
+
+    from repro.dist import steps
+    from repro.dist.sharding import to_shardings
+
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"), n_layers=7,
+                              experts_held=16)
+    batch, max_seq = 128, 4096
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    bundle = steps.build_serve_step(cfg, mesh, steps.StepConfig(),
+                                    batch=batch, max_seq=max_seq,
+                                    sample=True)
+
+    def sds(shapes, specs):
+        return jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            shapes, to_shardings(mesh, specs))
+
+    cache_shape = bundle.aux["cache_shape"]
+    compiled = bundle.fn.lower(
+        sds(bundle.aux["params_shape"], bundle.in_specs[0]),
+        sds(cache_shape, bundle.in_specs[1]),
+        jax.ShapeDtypeStruct((batch,), jnp.int32)).compile()
+
+    ring_bytes = sum(cache_shape[k].size * cache_shape[k].dtype.itemsize
+                     for k in ("ckv", "krope"))
+    assert ring_bytes > 4.2e9
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < ring_bytes / 64, (temp, ring_bytes)
+
+    whole = []
+    for k in ("ckv", "krope"):
+        ring = cache_shape[k].shape               # (L, B, S_buf, d)
+        whole += [sorted(ring), sorted(ring[1:])]
+    for m in re.finditer(r"= \w+\[([\d,]*)\]\S* copy\(", compiled.as_text()):
+        dims = [int(d) for d in m.group(1).split(",") if d and d != "1"]
+        assert sorted(dims) not in whole, m.group(0)

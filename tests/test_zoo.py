@@ -69,7 +69,8 @@ PAGED_ARCHS = tuple(n for n in ZOO
 
 #: MoE identity runs pin capacity_factor >= n_experts so no row overflows
 #: in either the bulk or the chunk-local program — the exactness condition
-#: of moe_chunk_agree_mask's bound.
+#: of moe_chunk_agree_mask's bound.  Dropless MoE (capacity_factor None)
+#: drops nothing and runs as it is.
 _NO_OVERFLOW = {"capacity_factor": 8.0}
 
 _PARAMS = {}
@@ -77,7 +78,7 @@ _PARAMS = {}
 
 def _zoo_cfg(arch):
     cfg = get_config(arch).reduced()
-    if arch in MOE_ARCHS:
+    if arch in MOE_ARCHS and cfg.capacity_factor is not None:
         cfg = dataclasses.replace(cfg, **_NO_OVERFLOW)
     return cfg
 
